@@ -190,6 +190,8 @@ def run_sharded_overlap() -> list:
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = os.path.abspath(src) + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    # A host-platform mesh by design: never take a chip the parent holds.
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run([sys.executable, "-c", _OVERLAP_BENCH], env=env,
                          capture_output=True, text=True, timeout=1200)
     rows: list[Row] = []

@@ -22,9 +22,16 @@ import sys
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
+from repro.launch.runtime_env import enable_compile_cache
+
 Row = Tuple[str, float, str]     # (name, us_per_call, derived)
 
 BENCH_SCHEMA_VERSION = 2
+
+# Every bench shares one persistent compilation cache: the directory in
+# JAX_COMPILATION_CACHE_DIR when set, else <repo>/.jax_cache.
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+enable_compile_cache(ROOT)
 
 
 def timeit(fn: Callable, *args, repeat: int = 3, **kw):

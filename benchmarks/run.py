@@ -30,6 +30,20 @@ if (importlib.util.find_spec("repro") is None
             sys.path.insert(0, _p)
 
 
+def _fleet_allowed() -> bool:
+    """May this process start the emulated multi-process fleet?  Its
+    ranks are CPU processes; on an accelerator host this process already
+    holds the chip, so the measured fleet rows are skipped there (the
+    model rows still run)."""
+    import jax
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    print(f"# scaling/real rows skipped: this process holds the {backend} "
+          "device, and the emulated CPU fleet is not started beside it")
+    return False
+
+
 def bench_all(out_dir: str, smoke: bool = False) -> int:
     """Write the committed perf-trajectory artifacts --
     BENCH_entropy.json, BENCH_chain.json, BENCH_compression.json,
@@ -47,6 +61,7 @@ def bench_all(out_dir: str, smoke: bool = False) -> int:
     from benchmarks.common import emit, write_bench_json
 
     failed = 0
+    real = _fleet_allowed()
     plan = [
         ("entropy", "BENCH_entropy.json",
          lambda: bench_entropy.run(smoke=True,
@@ -63,8 +78,8 @@ def bench_all(out_dir: str, smoke: bool = False) -> int:
              include_sharded=not smoke, include_chain=False),
          {"smoke": smoke, "note": "chain rows live in BENCH_chain.json"}),
         ("scaling", "BENCH_scaling.json",
-         lambda: bench_scaling.run(real=True, smoke=smoke),
-         {"smoke": smoke, "real": True,
+         lambda: bench_scaling.run(real=real, smoke=smoke),
+         {"smoke": smoke, "real": real,
           "note": "scaling/real/* rows are measured emulated multi-"
                   "process runs; the rest is the paper-scale model"}),
     ]

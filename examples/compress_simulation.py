@@ -13,11 +13,15 @@ from repro.baselines import isabela, zfp_like, zlib_lossless
 from repro.core import (NumarckParams, TemporalArchive, compress_series,
                         mean_error_rate, decompress_series)
 from repro.data.temporal import generate_series
+from repro.launch.runtime_env import enable_compile_cache
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 E = 1e-3
 
 
 def main():
+    enable_compile_cache(ROOT)
     variables = {name: list(generate_series(name, 4, seed=13, scale=2))
                  for name in ("stir", "asr")}
 

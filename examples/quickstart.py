@@ -2,14 +2,21 @@
 
     PYTHONPATH=src python examples/quickstart.py
 """
+import os
+import tempfile
+
 import numpy as np
 
 from repro.core import (NumarckParams, TemporalArchive, compress_series,
                         decompress_series, mean_error_rate)
 from repro.data.temporal import generate_series
+from repro.launch.runtime_env import enable_compile_cache
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def main():
+    enable_compile_cache(ROOT)
     # 6 snapshots of a turbulence-like field (FLASH-stir analogue)
     series = list(generate_series("stir", n_iterations=6, seed=0, scale=2))
     print(f"dataset: {len(series)} iterations x {series[0].shape} "
@@ -31,8 +38,9 @@ def main():
         assert mean_error_rate(orig, rec) <= params.error_bound * 1.01
 
     # write an archive + partial decompression
-    TemporalArchive.write("/tmp/quickstart.nck", "dens", steps)
-    ar = TemporalArchive("/tmp/quickstart.nck")
+    path = os.path.join(tempfile.mkdtemp(), "quickstart.nck")
+    TemporalArchive.write(path, "dens", steps)
+    ar = TemporalArchive(path)
     window = ar.read_range("dens", 5, 1000, 1200)
     np.testing.assert_array_equal(window,
                                   recon[5].reshape(-1)[1000:1200])

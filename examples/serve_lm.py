@@ -3,15 +3,20 @@
     PYTHONPATH=src python examples/serve_lm.py --arch llama3.2-1b
 """
 import argparse
+import os
 
 import jax
 import numpy as np
 
 from repro.models.model import build
 from repro.serve.engine import Engine
+from repro.launch.runtime_env import enable_compile_cache
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def main():
+    enable_compile_cache(ROOT)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--batch", type=int, default=4)

@@ -7,6 +7,7 @@ CPU; pass --full-width for the ~100M-parameter configuration (slower).
     PYTHONPATH=src python examples/train_restart.py
 """
 import argparse
+import os
 import shutil
 
 import jax
@@ -19,9 +20,13 @@ from repro.models.config import ModelConfig
 from repro.models.model import Model
 from repro.train import optim
 from repro.train.trainer import Trainer, TrainerConfig
+from repro.launch.runtime_env import enable_compile_cache
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def main():
+    enable_compile_cache(ROOT)
     ap = argparse.ArgumentParser()
     ap.add_argument("--full-width", action="store_true",
                     help="~100M params (slow on CPU)")

@@ -12,11 +12,9 @@ Three contracts of the host runtime (PR 3/5/6), one rule id:
      whose context expression ends in ``_lock``.
 
   2. **Process-pool dispatch only behind a ``holds_gil`` check.**  The
-     forked ``ProcessPoolExecutor`` exists solely because GIL-holding
+     spawned ``ProcessPoolExecutor`` exists solely because GIL-holding
      codecs get nothing from threads; dispatching GIL-releasing codecs
-     there pays pickle freight for negative win, and any *new*
-     process-pool call site multiplies the fork-after-jax exposure that
-     ``RansCodec`` deliberately opted out of.  Any function that touches
+     there pays pickle freight for negative win.  Any function that touches
      ``_shared_proc_pool`` must test ``holds_gil`` somewhere.
 
   3. **Every FinalizeQueue.submit names its task.**  Background-failure

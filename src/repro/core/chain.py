@@ -162,7 +162,7 @@ class DeviceReferenceChain(ReferenceChain):
         super().__init__()
         if use_pallas is None:
             use_pallas = jax.default_backend() == "tpu"
-        self._use_pallas = bool(use_pallas)
+        self.use_pallas = bool(use_pallas)
         self._shape: Optional[tuple] = None
 
     def seed(self, arr) -> None:
@@ -191,7 +191,7 @@ class DeviceReferenceChain(ReferenceChain):
         new = kops.chain_advance(idx, self._state.reshape(-1),
                                  curr_dev.reshape(-1), centers,
                                  b_bits=dev.enc.b_bits,
-                                 use_pallas=self._use_pallas)
+                                 use_pallas=self.use_pallas)
         self._state = new.reshape(self._shape)
 
     def to_host(self) -> np.ndarray:

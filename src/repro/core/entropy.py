@@ -14,7 +14,7 @@ module is our version of that idea:
     dispatched over a shared ``ThreadPoolExecutor``; zlib/bz2/lzma all
     release the GIL on the C side, so threads give real parallel speedup
     (see ``benchmarks/bench_entropy.py``).  Codecs that *hold* the GIL
-    (``Codec.holds_gil = True``) are dispatched over a forked
+    (``Codec.holds_gil = True``) are dispatched over a spawned
     ``ProcessPoolExecutor`` instead, with a transparent serial fallback
     when process pools are unavailable.
   * the ``"auto"`` pseudo-codec id -- :func:`resolve_codec` probes a
@@ -54,7 +54,7 @@ class Codec:
     name: str = "abstract"
     # Pure-python codecs that never release the GIL get no speedup from the
     # thread pool; mark them and compress_blocks dispatches them over a
-    # forked process pool instead.
+    # spawned process pool instead.
     holds_gil: bool = False
     # Codecs with a device-resident encoder: the drivers can entropy-code
     # index blocks on the accelerator (kernels.rans) and hand finalize
@@ -129,11 +129,10 @@ class RansCodec(Codec):
     """
 
     name = "rans"
-    # Deliberately NOT holds_gil: the process-pool dispatch would fork
-    # while the device entropy stage may be running jax on other threads
-    # (fork-after-jax is the hazard the pool's timeout only mitigates).
-    # The host flavor therefore serializes under the GIL -- it is the
-    # correctness/fallback path; throughput comes from the device stage.
+    # Deliberately NOT holds_gil: process-pool dispatch would ship every
+    # block by pickle to spawned workers.  The host flavor therefore
+    # serializes under the GIL -- it is the correctness/fallback path;
+    # throughput comes from the device stage.
     device = True
 
     def compress(self, raw: bytes, level: int) -> bytes:
@@ -283,23 +282,33 @@ def _shared_pool() -> ThreadPoolExecutor:
         return _pool
 
 
-def _shared_proc_pool() -> Optional[ProcessPoolExecutor]:
-    """Forked process pool for GIL-holding codecs.
+def _register_codecs(codecs: List[Codec]) -> None:
+    """Process-pool worker initializer: the parent's codec registry."""
+    for c in codecs:
+        register_codec(c)
 
-    Fork (not spawn) so workers inherit the codec registry, including
-    codecs registered after import; codecs registered after the pool's
-    first use are not visible to workers -- register before compressing.
-    Returns None where fork is unavailable (callers fall back to the
-    thread pool, which is correct, just not parallel).
+
+def _shared_proc_pool() -> Optional[ProcessPoolExecutor]:
+    """Spawned process pool for GIL-holding codecs.
+
+    Spawn, not fork: the parent may hold an accelerator (and JAX's
+    runtime threads), and a forked child would inherit that state.
+    Workers register the parent's codecs as they stand when the pool is
+    first used; codecs registered after that are not visible to workers
+    -- register before compressing.  Returns None where process pools
+    are unavailable (callers fall back to the thread pool, which is
+    correct, just not parallel).
     """
     global _proc_pool, _proc_pool_broken
     with _pool_lock:
         if _proc_pool is None and not _proc_pool_broken:
             try:
-                ctx = multiprocessing.get_context("fork")
+                ctx = multiprocessing.get_context("spawn")
                 workers = min(8, os.cpu_count() or 1)
-                _proc_pool = ProcessPoolExecutor(max_workers=workers,
-                                                 mp_context=ctx)
+                _proc_pool = ProcessPoolExecutor(
+                    max_workers=workers, mp_context=ctx,
+                    initializer=_register_codecs,
+                    initargs=(list(_REGISTRY.values()),))
             except (ValueError, OSError):
                 _proc_pool_broken = True
         return _proc_pool
@@ -374,12 +383,10 @@ def _dispatch_blocks(c: Codec, codec: str, raws: Sequence[bytes],
 
     if c.holds_gil and pool is None:
         # GIL-holding codec: threads would serialize, so fan batches out to
-        # forked worker processes instead (payload ships by pickle; the
-        # >= _TARGET_TASK_BYTES batching keeps the IPC amortized).  Workers
-        # run pure-python codec code only -- never jax -- which keeps the
-        # fork-after-jax-init hazard theoretical; the result timeout is the
-        # backstop: a wedged child degrades us to the thread path instead
-        # of hanging the finalize stage.
+        # spawned worker processes instead (payload ships by pickle; the
+        # >= _TARGET_TASK_BYTES batching keeps the IPC amortized).  The
+        # result timeout is the backstop: a wedged child degrades us to
+        # the thread path instead of hanging the finalize stage.
         px = _shared_proc_pool()
         if px is not None:
             workers = getattr(px, "_max_workers", os.cpu_count() or 1)
@@ -393,7 +400,7 @@ def _dispatch_blocks(c: Codec, codec: str, raws: Sequence[bytes],
                     out.extend(f.result(timeout=_PROC_RESULT_TIMEOUT_S))
                 return out
             except Exception:
-                # Sandboxed fork, wedged worker, codec error in the child:
+                # Sandboxed spawn, wedged worker, codec error in the child:
                 # retire the pool entirely (a wedged pool would otherwise
                 # re-stall every later call) and degrade to threads.  If
                 # the codec itself is at fault the thread path below

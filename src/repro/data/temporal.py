@@ -64,6 +64,10 @@ SPECS = {
     # CMIP: ocean current velocity (UVEL-like), smooth + repetitive
     "cmip": TemporalFieldSpec("cmip", (42, 360, 240), "float32",
                               vol=4e-3, jump_frac=0.002, static_frac=0.3),
+    # SDRBench Hurricane ISABEL: one f32 field of one hourly step at its
+    # published shape, 100 x 500 x 500 (100 MB per field per step)
+    "isabel": TemporalFieldSpec("isabel", (100, 500, 500), "float32",
+                                vol=1e-2, jump_frac=0.002, static_frac=0.05),
 }
 
 

@@ -36,17 +36,6 @@ def exclusive_scan_sum(x, axis: str):
     return jnp.tensordot(mask, gathered, axes=1)
 
 
-def axis_size(axis: str) -> int:
-    """Mesh-axis size inside shard_map.
-
-    `lax.axis_size` only exists in newer JAX; `psum(1, axis)` is the
-    portable spelling and returns a static int under shard_map.
-    """
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
-
-
 def right_edge_exchange(x_head, axis: str, fill):
     """Every shard receives the *head* slice of its right neighbour.
 
@@ -54,12 +43,12 @@ def right_edge_exchange(x_head, axis: str, fill):
     completed from the right neighbour's first elements.  The last shard
     receives `fill`.
     """
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(s, s - 1) for s in range(1, n)]
     recv = lax.ppermute(x_head, axis, perm)
     is_last = lax.axis_index(axis) == n - 1
     return jnp.where(is_last, fill, recv)
 
 
-__all__ = ["allreduce_minmax", "allreduce_sum", "axis_size",
-           "exclusive_scan_sum", "right_edge_exchange"]
+__all__ = ["allreduce_minmax", "allreduce_sum", "exclusive_scan_sum",
+           "right_edge_exchange"]

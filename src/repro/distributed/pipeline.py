@@ -46,7 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import binning, entropy, packing, ratios, select_b
 from repro.core import chain as chainmod
@@ -220,7 +220,7 @@ class ShardedCompressor:
                         fixed_domain=p.fixed_domain),
                 mesh=self.mesh,
                 in_specs=(P(self.axis), P(self.axis), P()),
-                out_specs=(P(),) * 6, check_rep=False)
+                out_specs=(P(),) * 6, check_vma=False)
             self._analyze_fns[key] = jax.jit(fn)
         return self._analyze_fns[key]
 
@@ -235,7 +235,7 @@ class ShardedCompressor:
                         use_pallas=self.use_pallas),
                 mesh=self.mesh,
                 in_specs=(P(self.axis), P(self.axis), P(), P(), P()),
-                out_specs=(P(self.axis),) * 3, check_rep=False)
+                out_specs=(P(self.axis),) * 3, check_vma=False)
             self._encode_fns[key] = jax.jit(fn)
         return self._encode_fns[key]
 
@@ -250,7 +250,7 @@ class ShardedCompressor:
                 partial(_entropy_shard, L=L),
                 mesh=self.mesh,
                 in_specs=(P(self.axis), P(self.axis)),
-                out_specs=(P(self.axis),) * 3, check_rep=False)
+                out_specs=(P(self.axis),) * 3, check_vma=False)
             self._entropy_fns[key] = jax.jit(fn)
         return self._entropy_fns[key]
 
@@ -303,7 +303,7 @@ class ShardedCompressor:
                         use_pallas=self.use_pallas),
                 mesh=self.mesh,
                 in_specs=(P(self.axis), P(self.axis), P(self.axis), P()),
-                out_specs=P(self.axis), check_rep=False)
+                out_specs=P(self.axis), check_vma=False)
             self._advance_fns[key] = jax.jit(fn)
         return self._advance_fns[key]
 
@@ -691,7 +691,7 @@ class ShardedDecompressor:
                         use_pallas=self.use_pallas),
                 mesh=self.mesh,
                 in_specs=(P(self.axis), P(self.axis), P()),
-                out_specs=P(self.axis), check_rep=False)
+                out_specs=P(self.axis), check_vma=False)
             self._dequant_fns[key] = jax.jit(fn)
         return self._dequant_fns[key]
 
@@ -704,7 +704,7 @@ class ShardedDecompressor:
             n_in = 4 if kind == "v2w" else 3
             fn = shard_map(partial(body, **static), mesh=self.mesh,
                            in_specs=(P(self.axis),) * n_in,
-                           out_specs=(P(self.axis),) * 3, check_rep=False)
+                           out_specs=(P(self.axis),) * 3, check_vma=False)
             self._rans_fns[key] = jax.jit(fn)
         return self._rans_fns[key]
 

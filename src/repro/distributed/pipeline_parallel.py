@@ -17,7 +17,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -77,7 +77,7 @@ def pipeline_apply(mesh: Mesh, axis: str, stage_fn: Callable,
     out = shard_map(
         shard_body, mesh=mesh,
         in_specs=(P(axis), P()),
-        out_specs=P(axis), check_rep=False)(stage_params, microbatches)
+        out_specs=P(axis), check_vma=False)(stage_params, microbatches)
     return out[0]
 
 

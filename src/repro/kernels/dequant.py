@@ -2,20 +2,27 @@
 
     out = prev * (1 + centers[idx])                 (corrected Eq. 4)
 
-TPU adaptation (DESIGN.md Sec. 3): TPUs have no fast VMEM gather, so the
-codebook lookup centers[idx] is computed as a **chunked one-hot matmul on
-the MXU** -- for each 1024-wide chunk of the codebook, build the one-hot
-matrix of the tile's indices against that chunk and contract with the chunk
-of centers.  For B <= 13 this is <= 8 MXU matvecs per tile, all VMEM-resident.
+TPU adaptation: TPUs have no fast VMEM gather, so the codebook lookup
+centers[idx] is a **two-level one-hot lookup**.  The padded codebook is
+laid out as a (LO, HI) table with centers[h * LO + l] at [l, h].  For one
+1024-lane row of indices, one MXU matmul of the table against the one-hot
+of ``idx // LO`` (HI, 1024) yields every candidate column (LO, 1024); a
+compare against ``idx % LO`` then selects one sublane per lane.
+
+The lookup must be exact: the chain promises bit-identity with the host
+NumPy reconstruction.  A float matmul may round the centers on the MXU, so
+the table holds the four *bytes* of each center's bit pattern as bf16
+(integers 0..255 are exact in bf16).  With one nonzero product per output
+the f32 accumulation is exact, and the bytes reassemble to the center's
+bits with integer shifts -- no arithmetic ever touches the value itself.
 
 Incompressible lanes (idx == 2^B - 1) are produced as 0 by the raw kernel;
 `patch_exceptions` scatters the exception table back over them **on
 device** (one `.at[].set`), so full reconstruction never has to leave the
 accelerator.  `dequantize_jnp` is the dtype-preserving gather path used
-for float64 chains (under jax_enable_x64) and as the no-Pallas fallback;
-for float32 it is bit-identical to the Pallas kernel (the one-hot MXU
-matmul is an exact select, and the elementwise `prev * (1 + c)` is the
-same IEEE f32 op in both lowerings).
+for float64 chains (under jax_enable_x64) and off the TPU; for float32 it
+is bit-identical to the Pallas kernel (exact lookup, then the same IEEE
+f32 ``prev * (1 + c)`` in both lowerings).
 """
 from __future__ import annotations
 
@@ -23,30 +30,60 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 LANE = 1024
 DEFAULT_BLOCK_ROWS = 64
-CHUNK = 1024            # codebook elements per one-hot matmul
+_HI_ALIGN = 16          # bf16 sublane tile
 
 
-def _kernel(idx_ref, prev_ref, centers_ref, out_ref, *, k_padded, marker):
-    idx = idx_ref[...]                          # (R, LANE) int32
-    prev = prev_ref[...]                        # (R, LANE) f32
-    r, lanes = idx.shape
-    flat = idx.reshape(r * lanes)
-    acc = jnp.zeros((r * lanes,), jnp.float32)
-    for base in range(0, k_padded, CHUNK):      # static unroll, <= 8 iters
-        local = flat - base
-        onehot = (local[:, None] ==
-                  jnp.arange(CHUNK, dtype=jnp.int32)[None, :])
-        chunk = centers_ref[pl.dslice(base, CHUNK)]
-        acc = acc + jnp.dot(onehot.astype(jnp.float32), chunk,
-                            preferred_element_type=jnp.float32)
-    centers_of = acc.reshape(r, lanes)
-    compressible = idx != marker
-    out = prev * (1.0 + centers_of)
-    out_ref[...] = jnp.where(compressible, out, 0.0)
+def _split(k: int):
+    """(LO, HI): a codebook of k centers as LO sublanes x HI columns.
+
+    LO ~ sqrt(k) balances the one-hot build (HI compares per element)
+    against the byte selects (4 * LO per element); HI pads to the bf16
+    sublane tile.  Indices at or past k (the marker) find an all-zero
+    one-hot column or a zero table entry, i.e. center 0, as in
+    `dequantize_jnp`'s zero-padded LUT."""
+    log_k = max(1, (k - 1).bit_length())
+    lo_w = min(128, max(8, 1 << -(-log_k // 2)))
+    hi_w = max(1, -(-k // lo_w))
+    return lo_w, -(-hi_w // _HI_ALIGN) * _HI_ALIGN
+
+
+def _byte_table(centers):
+    """(4 * LO, HI) bf16: byte j of bits(centers[h * LO + l]) at
+    [j * LO + l, h]; entries past the codebook are 0."""
+    k = centers.shape[0]
+    lo_w, hi_w = _split(k)
+    c = jnp.zeros((lo_w * hi_w,), jnp.float32).at[:k].set(
+        centers.astype(jnp.float32))
+    bits = lax.bitcast_convert_type(c, jnp.uint32).reshape(hi_w, lo_w).T
+    planes = [(bits >> (8 * j)) & 0xFF for j in range(4)]
+    return jnp.concatenate(planes, axis=0).astype(jnp.bfloat16)
+
+
+def _kernel(idx_ref, prev_ref, tab_ref, out_ref, *, marker, lo_w, hi_w):
+    tab = tab_ref[...]                              # (4 * LO, HI) bf16
+    hi_iota = lax.broadcasted_iota(jnp.int32, (hi_w, LANE), 0)
+    lo_iota = lax.broadcasted_iota(jnp.int32, (lo_w, LANE), 0)
+    shift = lo_w.bit_length() - 1
+
+    @pl.loop(0, idx_ref.shape[0])
+    def _row(r):
+        idx = idx_ref[pl.ds(r, 1), :]               # (1, LANE) int32
+        onehot = (hi_iota == (idx >> shift)).astype(jnp.bfloat16)
+        cols = jnp.dot(tab, onehot, preferred_element_type=jnp.float32)
+        pick = lo_iota == (idx & (lo_w - 1))
+        bits = jnp.zeros_like(idx)
+        for j in range(4):                          # static: 4 bytes
+            byte = jnp.sum(jnp.where(pick, cols[j * lo_w:(j + 1) * lo_w],
+                                     0.0), axis=0, keepdims=True)
+            bits = bits | (byte.astype(jnp.int32) << (8 * j))
+        c = lax.bitcast_convert_type(bits, jnp.float32)
+        out = prev_ref[pl.ds(r, 1), :] * (1.0 + c)
+        out_ref[pl.ds(r, 1), :] = jnp.where(idx == marker, 0.0, out)
 
 
 @functools.partial(jax.jit,
@@ -61,9 +98,8 @@ def dequantize(idx: jax.Array, prev: jax.Array, centers: jax.Array, *,
     """
     n = idx.shape[0]
     marker = (1 << b_bits) - 1
-    k_padded = max(CHUNK, pl.cdiv(centers.shape[0], CHUNK) * CHUNK)
-    centers_p = jnp.pad(centers.astype(jnp.float32),
-                        (0, k_padded - centers.shape[0]))
+    lo_w, hi_w = _split(centers.shape[0])
+    tab = _byte_table(centers)
 
     rows = pl.cdiv(n, LANE)
     rows_pad = pl.cdiv(rows, block_rows) * block_rows
@@ -76,14 +112,13 @@ def dequantize(idx: jax.Array, prev: jax.Array, centers: jax.Array, *,
     grid = (rows_pad // block_rows,)
     blk = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
     out = pl.pallas_call(
-        functools.partial(_kernel, k_padded=k_padded, marker=marker),
+        functools.partial(_kernel, marker=marker, lo_w=lo_w, hi_w=hi_w),
         grid=grid,
-        in_specs=[blk, blk,
-                  pl.BlockSpec((k_padded,), lambda i: (0,))],
+        in_specs=[blk, blk, pl.BlockSpec(tab.shape, lambda i: (0, 0))],
         out_specs=blk,
         out_shape=jax.ShapeDtypeStruct((rows_pad, LANE), jnp.float32),
         interpret=interpret,
-    )(idx2, prev2, centers_p)
+    )(idx2, prev2, tab)
     return out.reshape(-1)[:n]
 
 
@@ -94,7 +129,7 @@ def dequantize_jnp(idx: jax.Array, prev: jax.Array, centers: jax.Array, *,
 
     Arithmetic runs in `prev.dtype` -- the float64 chain path under
     jax_enable_x64 -- and for float32 inputs is bit-identical to the
-    Pallas one-hot-MXU kernel.  Marker lanes return 0 like `dequantize`.
+    Pallas byte-table kernel.  Marker lanes return 0 like `dequantize`.
     """
     idx = jnp.asarray(idx)
     prev = jnp.asarray(prev)

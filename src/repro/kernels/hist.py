@@ -1,12 +1,17 @@
 """Pallas TPU kernel: candidate-bin histogram (phase 2 counting pass).
 
-TPU adaptation: there is no atomic scatter-add on TPU; the histogram is
-computed as a **comparison + reduce** over codomain chunks.  Grid is
-(element_tiles, bin_chunks); each step counts the tile's hits inside one
-1024-bin chunk with a broadcast compare and accumulates into the output
-block (sequential TPU grid => safe read-modify-write revisiting).
+TPU adaptation: there is no atomic scatter-add on TPU, so the histogram
+is a **two-level one-hot contraction on the MXU**.  A bin id splits as
+``id = hi * 256 + lo``; for one 1024-lane row of ids, the one-hots of hi
+(HI, 1024) and lo (256, 1024) contract over the lanes into a (HI, 256)
+count tile -- every bin of the row at once.  One-hot entries are exact in
+bf16 and a tile's counts stay far below 2^24, so the f32 accumulation is
+exact; each element tile then adds its counts to the int32 output.
 
-Invalid elements carry bin_id == -1 and never match a chunk lane.
+The output block is the same for every grid step, so it stays resident in
+VMEM and is written back once at the end (the accumulation axis is the
+only, sequential, grid axis).  Invalid elements carry bin_id == -1, whose
+hi part (-1) matches no one-hot row.
 """
 from __future__ import annotations
 
@@ -14,30 +19,36 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANE = 1024
 DEFAULT_BLOCK_ROWS = 64
-BIN_CHUNK = 1024
+BIN_CHUNK = 1024        # max_bins granularity
+LO_BINS = 256           # bins per one-hot row of the lo part
+_HI_ALIGN = 16          # bf16 sublane tile
 
 
-def _kernel(id_ref, out_ref):
-    i = pl.program_id(0)        # element tile (major, sequential)
-    j = pl.program_id(1)        # bin chunk
-
-    @pl.when(i == 0)
+def _kernel(id_ref, out_ref, *, hi_w):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    ids = id_ref[...].reshape(-1)
-    base = j * BIN_CHUNK
-    local = ids - base
-    onehot = (local[:, None] == jnp.arange(BIN_CHUNK,
-                                           dtype=jnp.int32)[None, :])
-    # Accumulate in the output ref's dtype: under jax_enable_x64 the sum
-    # would otherwise promote to int64 and fail the int32 ref store.
-    counts = jnp.sum(onehot, axis=0, dtype=out_ref.dtype)
-    out_ref[...] += counts
+    hi_iota = lax.broadcasted_iota(jnp.int32, (hi_w, LANE), 0)
+    lo_iota = lax.broadcasted_iota(jnp.int32, (LO_BINS, LANE), 0)
+
+    def row(r, acc):
+        ids = id_ref[pl.ds(r, 1), :]                # (1, LANE) int32
+        hi = (hi_iota == (ids >> 8)).astype(jnp.bfloat16)
+        lo = (lo_iota == (ids & (LO_BINS - 1))).astype(jnp.bfloat16)
+        return acc + lax.dot_general(
+            hi, lo, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    acc = lax.fori_loop(0, id_ref.shape[0], row,
+                        jnp.zeros((hi_w, LO_BINS), jnp.float32))
+    out_ref[...] += acc.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -52,16 +63,18 @@ def histogram(bin_ids: jax.Array, *, max_bins: int,
     rows_pad = pl.cdiv(rows, block_rows) * block_rows
     ids2 = jnp.pad(bin_ids, (0, rows_pad * LANE - n),
                    constant_values=-1).reshape(rows_pad, LANE)
-    grid = (rows_pad // block_rows, max_bins // BIN_CHUNK)
+    hi_w = pl.cdiv(max_bins // LO_BINS, _HI_ALIGN) * _HI_ALIGN
     out = pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, LANE), lambda i, j: (i, 0))],
-        out_specs=pl.BlockSpec((BIN_CHUNK,), lambda i, j: (j,)),
-        out_shape=jax.ShapeDtypeStruct((max_bins,), jnp.int32),
+        functools.partial(_kernel, hi_w=hi_w),
+        grid=(rows_pad // block_rows,),
+        in_specs=[pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((hi_w, LO_BINS), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((hi_w, LO_BINS), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(ids2)
-    return out
+    return out.reshape(-1)[:max_bins]
 
 
 __all__ = ["histogram"]

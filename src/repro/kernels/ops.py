@@ -38,7 +38,7 @@ def pack_bits(idx, *, b_bits, use_pallas: bool = True):
 
 
 def dequantize(idx, prev, centers, *, b_bits, use_pallas: bool = True):
-    # The Pallas one-hot-MXU kernel is f32-only; other dtypes (the f64
+    # The Pallas byte-table kernel is f32-only; other dtypes (the f64
     # chain under jax_enable_x64) take the dtype-preserving gather path,
     # which is bit-identical for f32 anyway.
     if not use_pallas or jnp.asarray(prev).dtype != jnp.float32:

@@ -125,9 +125,14 @@ def rank_env(rank: int, num_processes: int, coordinator: str, *,
              base: Optional[Dict[str, str]] = None,
              preset: bool = True) -> Dict[str, str]:
     """Child environment for emulated rank `rank`: fleet coordinates plus
-    the runtime preset (tcmalloc / log level / XLA host-device flag)."""
+    the runtime preset (tcmalloc / log level / XLA host-device flag).
+
+    Emulated ranks are CPU processes by design (gloo collectives), so the
+    child is pinned to ``JAX_PLATFORMS=cpu``: on an accelerator host it
+    must never try to take a chip that its parent may hold."""
     env = (runtime_env(base, host_device_count=devices_per_process)
            if preset else dict(os.environ if base is None else base))
+    env["JAX_PLATFORMS"] = "cpu"
     if not preset and devices_per_process != 1:
         from repro.launch.runtime_env import merge_xla_flags
         env["XLA_FLAGS"] = merge_xla_flags(

@@ -318,7 +318,7 @@ def run_compression_dryrun(mesh_kind: str, out_dir=None,
     """
     from repro.core.types import NumarckParams
     from repro.distributed import pipeline as pl
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
     axis_names = mesh.axis_names
@@ -333,7 +333,7 @@ def run_compression_dryrun(mesh_kind: str, out_dir=None,
                     b_max=params.b_max, elem_bytes=4, n_total=n_elems,
                     axis=axis_names[0], use_pallas=False),
             mesh=mesh, in_specs=(P(axis_names[0]), P(axis_names[0]), P()),
-            out_specs=(P(axis_names[0]),) * 6, check_rep=False)
+            out_specs=(P(axis_names[0]),) * 6, check_vma=False)
         # NB: shard over the first axis only for the collective pattern the
         # paper has (one flat allreduce); remaining axes replicate.
         n_shards = mesh.shape[axis_names[0]]

@@ -12,10 +12,16 @@ way (HomebrewNLP/olmax run.sh, MaxText MultiHostJob -- SNIPPETS §1-3):
   * the CPU emulation path needs ``--xla_force_host_platform_device_count``
     set *before* jax imports, so it must travel via the child environment.
 
-Everything here is a pure dict-in/dict-out helper: nothing touches
-``os.environ`` of the calling process, and importing this module never
-imports jax (launchers build child environments long before jax exists
-in the child).
+Everything here except ``enable_compile_cache`` is a pure dict-in/dict-out
+helper: nothing touches ``os.environ`` of the calling process, and
+importing this module never imports jax (launchers build child
+environments long before jax exists in the child).
+
+``enable_compile_cache`` is the one in-process setting: it points JAX's
+persistent compilation cache at a directory that can be placed from
+outside (``JAX_COMPILATION_CACHE_DIR``) and is otherwise fixed inside
+the checkout, so a second run of the same program finds its compiled
+kernels again.
 """
 from __future__ import annotations
 
@@ -36,6 +42,29 @@ TCMALLOC_CANDIDATES = (
 # ~60 GB, the olmax value: effectively "never report" without disabling
 # the accounting entirely.
 TCMALLOC_REPORT_THRESHOLD = "60000000000"
+
+
+# Persistent compilation cache: where JAX keeps compiled executables.
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+COMPILE_CACHE_DIRNAME = ".jax_cache"
+
+
+def enable_compile_cache(root: str) -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and no
+    other directory is set here.  Otherwise the cache lives at the fixed
+    ``<root>/.jax_cache`` (``root`` is the checkout the caller runs
+    from): a path built from a temp name, a pid or the time would never
+    be found again by the next run.  Call before the first compile.
+    """
+    path = os.environ.get(COMPILE_CACHE_ENV)
+    if path:
+        return path
+    import jax
+    path = os.path.join(os.path.abspath(root), COMPILE_CACHE_DIRNAME)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def find_tcmalloc(candidates=TCMALLOC_CANDIDATES) -> Optional[str]:
@@ -89,5 +118,6 @@ def runtime_env(base: Optional[Dict[str, str]] = None, *,
     return env
 
 
-__all__ = ["find_tcmalloc", "merge_xla_flags", "runtime_env",
+__all__ = ["enable_compile_cache", "find_tcmalloc", "merge_xla_flags",
+           "runtime_env", "COMPILE_CACHE_ENV", "COMPILE_CACHE_DIRNAME",
            "TCMALLOC_CANDIDATES", "TCMALLOC_REPORT_THRESHOLD"]

@@ -2,7 +2,7 @@
 import functools
 
 import jax
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 @jax.jit

@@ -62,18 +62,24 @@ def test_bitpack_kernel_sizes(n_groups):
     np.testing.assert_array_equal(w_k, ref.pack_bits_ref(idx, b_bits=b))
 
 
-@pytest.mark.parametrize("b_bits", [2, 5, 8, 13])
+@pytest.mark.parametrize("b_bits", [2, 5, 8, 13, 16])
 @pytest.mark.parametrize("n", [17, 2048, 100_001])
 def test_dequant_kernel(b_bits, n):
+    """The byte-table lookup is exact: bit-identical to the jnp oracle and
+    the gather lowering, marker lanes included."""
     k = (1 << b_bits) - 1
     centers = RNG.uniform(-0.1, 0.1, k).astype(np.float32)
+    centers[: min(k, 4)] = [0.0, -0.0, 1e-30, -3.5e-3][: min(k, 4)]
     idx = RNG.integers(0, k + 1, n).astype(np.int32)
     prev = RNG.normal(1, 0.5, n).astype(np.float32)
     out_k = np.asarray(dequant.dequantize(
         jnp.asarray(idx), jnp.asarray(prev), jnp.asarray(centers),
         b_bits=b_bits, interpret=True))
     out_r = np.asarray(ref.dequantize_ref(idx, prev, centers, b_bits=b_bits))
-    np.testing.assert_allclose(out_k, out_r, rtol=1e-6, atol=1e-7)
+    out_j = np.asarray(dequant.dequantize_jnp(idx, prev, centers,
+                                              b_bits=b_bits))
+    np.testing.assert_array_equal(out_k, out_r)
+    np.testing.assert_array_equal(out_k, out_j)
 
 
 @pytest.mark.parametrize("max_bins", [1024, 4096, 65536])
@@ -85,6 +91,24 @@ def test_hist_kernel(max_bins, n):
     h_r = np.asarray(ref.histogram_ref(ids, max_bins=max_bins))
     np.testing.assert_array_equal(h_k, h_r)
     assert h_k.sum() == (ids >= 0).sum()
+
+
+@pytest.mark.parametrize("block_rows", [8, 64])
+def test_hist_kernel_many_tiles_all_chunks(block_rows):
+    """Several element tiles accumulate into one resident output block:
+    every one of the 64 1024-bin chunks is hit, and one hot bin collects
+    counts from every tile."""
+    max_bins = 65536
+    n = 4 * 64 * 1024 + 777
+    ids = RNG.integers(-1, max_bins, n).astype(np.int32)
+    ids[::3] = 40_000                        # hot bin, in every tile
+    ids[:64] = np.arange(64) * 1024 + 1023   # last bin of every chunk
+    h_k = np.asarray(hist.histogram(jnp.asarray(ids), max_bins=max_bins,
+                                    block_rows=block_rows, interpret=True))
+    h_r = np.asarray(ref.histogram_ref(ids, max_bins=max_bins))
+    np.testing.assert_array_equal(h_k, h_r)
+    assert (h_k.reshape(64, 1024).sum(axis=1) > 0).all()
+    assert h_k[40_000] == (ids == 40_000).sum() > 64 * 1024
 
 
 def test_pack_matches_core_packing_bytes():

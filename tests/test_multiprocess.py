@@ -81,6 +81,38 @@ def test_merge_xla_flags_dedups_by_key():
     assert "--xla_force_host_platform_device_count=2" not in merged
 
 
+_CACHE_CHILD = textwrap.dedent("""
+    import sys
+    import jax
+    import jax.numpy as jnp
+    from repro.launch.runtime_env import enable_compile_cache
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    print(enable_compile_cache(sys.argv[1]))
+    jax.jit(lambda x: jnp.sin(x) * 3 + 1)(jnp.ones(257)).block_until_ready()
+""")
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_directory(tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins and no other directory is set;
+    otherwise the cache sits at the fixed <root>/.jax_cache."""
+    root = tmp_path / "checkout"
+    env = {k: v for k, v in os.environ.items()
+           if k != renv.COMPILE_CACHE_ENV}
+    env.update(PYTHONPATH=_SRC, JAX_PLATFORMS="cpu")
+    want = root / renv.COMPILE_CACHE_DIRNAME
+    if from_env:
+        want = tmp_path / "placed"
+        env[renv.COMPILE_CACHE_ENV] = str(want)
+    out = subprocess.run([sys.executable, "-c", _CACHE_CHILD, str(root)],
+                         env=env, capture_output=True, text=True,
+                         timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip().splitlines()[-1] == str(want)
+    assert any(want.iterdir()), "no compiled executable was cached"
+    assert root.exists() != from_env
+
+
 def test_rank_env_coordinates():
     env = rank_env(1, 4, "localhost:1234", devices_per_process=2,
                    base={}, preset=True)
@@ -88,6 +120,14 @@ def test_rank_env_coordinates():
     assert env[ENV_NUM_PROCESSES] == "4"
     assert env[ENV_PROCESS_ID] == "1"
     assert "--xla_force_host_platform_device_count=2" in env["XLA_FLAGS"]
+
+
+@pytest.mark.parametrize("preset", [True, False])
+def test_rank_env_pins_emulated_ranks_to_cpu(preset):
+    """Emulated ranks never try to take an accelerator their parent holds."""
+    env = rank_env(0, 2, "localhost:1234", base={"JAX_PLATFORMS": "tpu"},
+                   preset=preset)
+    assert env["JAX_PLATFORMS"] == "cpu"
 
 
 def test_spawn_emulated_ranks_and_failure_reporting():
