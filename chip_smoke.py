@@ -6,7 +6,7 @@
 
 Data: one f32 field at SDRBench Hurricane ISABEL's per-field shape
 (100 x 500 x 500, 100 MB per step), generated on the host from ``--seed``
-by ``repro.data.temporal`` (spec "isabel"): one anchor and ``--steps - 1``
+by ``repro.data.temporal`` (spec "isabel"): one anchor and ``STEPS - 1``
 deltas.  Generation stays out of every timing.
 
   A  ``compress_series`` (default ``chain="auto"``: the device-resident
@@ -19,8 +19,13 @@ deltas.  Generation stays out of every timing.
      restores a delta step bit-identically to the host ``decompress_step``.
   C  ``ShardedCompressor`` over a mesh of one chip with its Pallas kernels:
      blobs and centers byte-identical to phase A; the ``hist`` kernel's
-     counts equal ``ref.histogram_ref``; every Pallas wrapper lowers to a
-     ``tpu_custom_call`` (not interpret mode, not the jnp reference).
+     counts equal ``ref.histogram_ref``.
+
+In every phase the ``kernels.ops`` wrappers are watched while the entry
+point runs: each wrapper it called must have been called with
+``use_pallas=True``, and each such wrapper must lower to a
+``tpu_custom_call`` on the chip (not interpret mode, not the jnp
+reference).
 
 With ``--chips 4`` only the mesh phase runs: ``ShardedCompressor`` over
 four chips (histogram psum, pmin/pmax, edge ppermute), byte-identical to
@@ -33,6 +38,7 @@ when any check fails; its last line on success is one JSON object.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import dataclasses
 import json
 import os
@@ -42,9 +48,12 @@ import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 VAR = "isabel"
+STEPS = 4                       # one anchor and three deltas
 ERROR_BOUND = 1e-3
 # Pallas wrappers of the encode/decode path (kernels.ops) whose lowering
 # must contain the Mosaic custom call on the chip.
+KERNELS = ("change_ratio_bins", "histogram", "pack_bits", "dequantize",
+           "chain_advance")
 NATIVE_MARK = "tpu_custom_call"
 
 
@@ -61,11 +70,10 @@ def timed(fn, *args, **kw):
     return time.perf_counter() - t0, out
 
 
-def make_series(seed: int, steps: int, scale: int = 1):
+def make_series(seed: int):
     from repro.data.temporal import generate_series
     t0 = time.perf_counter()
-    series = list(generate_series(VAR, n_iterations=steps, seed=seed,
-                                  scale=scale))
+    series = list(generate_series(VAR, n_iterations=STEPS, seed=seed))
     log(f"data: {len(series)} steps of {series[0].shape} "
         f"{series[0].dtype} ({series[0].nbytes / 1e6:.1f} MB each), "
         f"generated on the host in {time.perf_counter() - t0:.1f} s "
@@ -98,12 +106,43 @@ def assert_native(lowered_text: str, what: str) -> None:
         "interpret mode or the jnp reference ran instead")
 
 
-def check_kernels_native(n: int, b_bits: int, max_bins: int) -> None:
-    """Every Pallas wrapper the encode/decode path calls, lowered with its
-    default arguments at this phase's shapes."""
+@contextlib.contextmanager
+def kernel_calls():
+    """Record ``(wrapper, use_pallas)`` for every ``kernels.ops`` wrapper
+    in ``KERNELS`` that the code under the block calls or traces."""
+    from repro.kernels import ops as kops
+    seen = []
+    saved = {name: getattr(kops, name) for name in KERNELS}
+
+    def spy(name, fn):
+        def call(*args, **kw):
+            seen.append((name, kw.get("use_pallas", True)))
+            return fn(*args, **kw)
+        return call
+
+    for name, fn in saved.items():
+        setattr(kops, name, spy(name, fn))
+    try:
+        yield seen
+    finally:
+        for name, fn in saved.items():
+            setattr(kops, name, fn)
+
+
+def check_kernels_native(seen, expected, n: int, b_bits: int,
+                         max_bins: int) -> None:
+    """The entry point called the `expected` wrappers (recorded in `seen`
+    by ``kernel_calls``) and only with ``use_pallas=True``; each wrapper it
+    called, lowered that way at this phase's shapes, is a Mosaic kernel."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import ops as kops
+    called = {name for name, _ in seen}
+    missing = set(expected) - called
+    assert not missing, f"entry point never called {sorted(missing)}"
+    jnp_calls = sorted({name for name, pallas in seen if not pallas})
+    assert not jnp_calls, (
+        f"entry point called {jnp_calls} with use_pallas=False")
     f32 = jax.ShapeDtypeStruct((n,), jnp.float32)
     i32 = jax.ShapeDtypeStruct((n,), jnp.int32)
     k = (1 << b_bits) - 1
@@ -121,9 +160,12 @@ def check_kernels_native(n: int, b_bits: int, max_bins: int) -> None:
         "chain_advance": (lambda i, p, q, c: kops.chain_advance(
             i, p, q, c, b_bits=b_bits), (i32, f32, f32, cen)),
     }
-    for name, (fn, args) in cases.items():
+    ran = [name for name in KERNELS if name in called]
+    for name in ran:
+        fn, args = cases[name]
         assert_native(jax.jit(fn).lower(*args).as_text(), name)
-    log(f"  kernels lower to {NATIVE_MARK}: {', '.join(cases)}")
+    log(f"  kernels the entry point ran, all with use_pallas=True, lower "
+        f"to {NATIVE_MARK}: {', '.join(ran)}")
 
 
 def check_error_bound(series, recon) -> None:
@@ -151,23 +193,23 @@ def check_error_bound(series, recon) -> None:
 def phase_a(series, workdir: str):
     """Single-chip main path; returns (steps, restored arrays)."""
     import numpy as np
-    from repro.core import (CHAIN_AUTO, CHAIN_DEVICE, CHAIN_HOST,
-                            NumarckParams, TemporalArchive,
+    from repro.core import (CHAIN_HOST, NumarckParams, TemporalArchive,
                             TemporalCompressor, compress_series,
-                            decompress_series, make_reference_chain)
+                            decompress_series)
     log("phase A: compress_series -> NCKWriter -> TemporalArchive")
     params = NumarckParams(error_bound=ERROR_BOUND)
-    chain = make_reference_chain(CHAIN_AUTO, series[0].dtype)
-    assert chain.residency == CHAIN_DEVICE and chain.use_pallas, (
-        "chain='auto' must pick the device chain with the Pallas dequant")
     n = len(series)
-    setup_s, steps = timed(compress_series, series, params)
+    with kernel_calls() as seen:
+        setup_s, steps = timed(compress_series, series, params)
     warm_s, again = timed(compress_series, series, params)
     assert_same_steps(again, steps, "repeat compress")
     b_bits = sorted({s.b_bits for s in steps[1:]})
     log(f"  compressed {n} steps, B = {b_bits}, compression ratio "
         f"{sum(a.nbytes for a in series) / sum(s.nbytes for s in steps):.2f}")
-    check_kernels_native(series[0].size, max(b_bits), params.max_bins)
+    # chain="auto" must have advanced the device chain (chain_advance)
+    # through the Pallas dequantize.
+    check_kernels_native(seen, ("chain_advance", "dequantize"),
+                         series[0].size, max(b_bits), params.max_bins)
 
     path = os.path.join(workdir, f"{VAR}.nck")
     write_s, _ = timed(TemporalArchive.write, path, VAR, steps)
@@ -209,7 +251,8 @@ def phase_b(series, steps_a, recon):
     log("phase B: codec='rans' device entropy and device decode")
     params = NumarckParams(error_bound=ERROR_BOUND, codec="rans")
     n = series[0].size
-    setup_s, dev_steps = timed(compress_series, series, params)
+    with kernel_calls() as seen:
+        setup_s, dev_steps = timed(compress_series, series, params)
     warm_s, _ = timed(compress_series, series, params)
     assert all(comp.device_entropy_route(params, n, s.b_bits)
                for s in dev_steps[1:]), "device entropy route not taken"
@@ -222,7 +265,12 @@ def phase_b(series, steps_a, recon):
     t = len(series) - 1
     step = dev_steps[t]
     assert comp.device_decode_route(step)
-    comp.decompress_step_device(step, recon[t - 1])        # warm-up
+    with kernel_calls() as seen_dec:
+        comp.decompress_step_device(step, recon[t - 1])    # warm-up
+    check_kernels_native(seen, ("chain_advance",), n, step.b_bits,
+                         params.max_bins)
+    check_kernels_native(seen_dec, ("dequantize",), n, step.b_bits,
+                         params.max_bins)
     dec_s, dev_out = timed(comp.decompress_step_device, step, recon[t - 1])
     saved = rans.DEVICE_MIN_BYTES
     rans.DEVICE_MIN_BYTES = float("inf")     # force the host decode route
@@ -255,9 +303,9 @@ def phase_c(series, steps_a, recon, devices):
     params = NumarckParams(error_bound=ERROR_BOUND)
     sc = ShardedCompressor(Mesh(np.asarray(devices), ("data",)), "data",
                            params)
-    assert sc.use_pallas
     try:
-        setup_s, steps_c = timed(sc.compress_series, series)
+        with kernel_calls() as seen:
+            setup_s, steps_c = timed(sc.compress_series, series)
         warm_s, _ = timed(sc.compress_series, series)
     finally:
         sc.close()
@@ -277,7 +325,11 @@ def phase_c(series, steps_a, recon, devices):
     log(f"  hist kernel: {params.max_bins} bin counts equal histogram_ref "
         f"({int(got.sum())} binned elements, {int((got > 0).sum())} "
         "bins occupied)")
-    check_kernels_native(series[0].size, max(s.b_bits for s in steps_a[1:]),
+    # The mesh stages: analyze (change_ratio_bins, histogram), encode
+    # (pack_bits) and the chain advance on the mesh (dequantize).
+    check_kernels_native(seen, ("change_ratio_bins", "histogram",
+                                "pack_bits", "dequantize"),
+                         series[0].size, max(s.b_bits for s in steps_a[1:]),
                          params.max_bins)
     log(f"  smoke timing (not a benchmark): set-up {setup_s:.2f} s; "
         f"compress {warm_s / len(series):.3f} s/step")
@@ -301,11 +353,7 @@ def main(argv=None) -> int:
                     help="1: phases A-C on one chip; 4: only the sharded "
                          "mesh phase over four chips")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--steps", type=int, default=4,
-                    help="steps in the series (one anchor + deltas)")
     args = ap.parse_args(argv)
-    if args.steps < 2:
-        ap.error("--steps must be at least 2 (an anchor and a delta)")
 
     import jax
     devices = jax.devices()
@@ -325,7 +373,7 @@ def main(argv=None) -> int:
     from repro.launch.runtime_env import enable_compile_cache
     log(f"compile cache: {enable_compile_cache(ROOT)}")
 
-    series = make_series(args.seed, args.steps)
+    series = make_series(args.seed)
     t0 = time.perf_counter()
     if args.chips == 4:
         phase_mesh(series, devices[:4])
