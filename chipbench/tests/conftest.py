@@ -1,0 +1,9 @@
+"""CPU tests of the benchmark: run them by path (``pytest chipbench/tests``)."""
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
