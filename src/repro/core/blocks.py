@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.core import entropy, packing
 from repro.core import pipeline as pipe
+from repro.obs import telemetry
 
 
 def block_slices(n: int, block_elems: int) -> List[Tuple[int, int]]:
@@ -46,9 +47,11 @@ def deflate_blocks(idx: np.ndarray, b_bits: int, block_elems: int,
 
 def inflate_block(blob: bytes, n_elems: int, b_bits: int,
                   codec: str = entropy.DEFAULT_CODEC) -> np.ndarray:
-    packed = np.frombuffer(entropy.decompress_block(blob, codec),
-                           dtype=np.uint8)
-    return packing.unpack_indices_np(packed, n_elems, b_bits)
+    with telemetry.span("decode.inflate"):
+        packed = np.frombuffer(entropy.decompress_block(blob, codec),
+                               dtype=np.uint8)
+    with telemetry.span("decode.unpack"):
+        return packing.unpack_indices_np(packed, n_elems, b_bits)
 
 
 def zlib_ratio(blocks: List[bytes], raw_sizes: np.ndarray) -> float:

@@ -95,7 +95,7 @@ def decode_anchor(step: CompressedStep) -> np.ndarray:
     and only the finished bytes cross back; otherwise the host codec
     registry inflates the blocks (pool-parallel)."""
     tele = telemetry.enabled()
-    with telemetry.span("decode.entropy", annotate=True) as sp_e:
+    with telemetry.span("decode.entropy") as sp_e:
         if device_decode_route(step):
             flat = rans.decode_bytes_blocks_device(
                 step.index_blocks, pool=entropy._shared_pool())
@@ -132,7 +132,7 @@ def decode_anchor_device(step: CompressedStep) -> jax.Array:
     if not (device_ok and device_decode_route(step)):
         return jnp.asarray(decode_anchor(step))
     tele = telemetry.enabled()
-    with telemetry.span("decode.entropy", annotate=True) as sp_e:
+    with telemetry.span("decode.entropy") as sp_e:
         flat = rans.decode_bytes_blocks_device(
             step.index_blocks, pool=entropy._shared_pool())
         out = jax.lax.bitcast_convert_type(
@@ -218,15 +218,14 @@ def encode_device(prev, curr, params: NumarckParams,
     # durations mean "stage time", not "async dispatch time"; with
     # telemetry disabled dispatch stays fully asynchronous.
     tele = telemetry.enabled()
-    with telemetry.span("encode.analyze", annotate=True) as sp_an:
+    with telemetry.span("encode.analyze") as sp_an:
         a = _analyze(prev.reshape(-1), curr.reshape(-1),
                      np.float32(params.error_bound), params.max_bins,
                      params.b_max, ebytes)
         if tele:
             jax.block_until_ready(a)
 
-    with telemetry.span("encode.index", annotate=True,
-                        strategy=params.strategy) as sp_idx:
+    with telemetry.span("encode.index", strategy=params.strategy) as sp_idx:
         if params.strategy == STRATEGY_TOPK:
             b_bits = int(params.b_bits if params.b_bits is not None
                          else a["b_auto"])
@@ -272,7 +271,7 @@ def encode_device(prev, curr, params: NumarckParams,
     # finalize consumes the finished blobs (byte-identical to the host
     # codec flavor, so routing never changes the file format).
     coded = coded_name = None
-    with telemetry.span("encode.device_entropy", annotate=True) as sp_de:
+    with telemetry.span("encode.device_entropy") as sp_de:
         if device_entropy_route(params, n, b_bits):
             nblocks = -(-n // be)
             idx_pad = jnp.pad(idx, (0, nblocks * be - n),
@@ -389,21 +388,21 @@ def decompress_step_device(step: CompressedStep, prev) -> jax.Array:
     assert prev is not None, "non-anchor steps need the previous state"
     tele = telemetry.enabled()
     cdt = pipe.reconstruction_dtype(step.dtype)
-    with telemetry.span("decode.entropy", annotate=True) as sp_e:
+    with telemetry.span("decode.entropy") as sp_e:
         idx2d = rans.decode_blocks_device(step.index_blocks, step.b_bits,
                                           step.block_elems,
                                           pool=entropy._shared_pool())
         idx = idx2d.reshape(-1)[:step.n]
         if tele:
             jax.block_until_ready(idx)
-    with telemetry.span("decode.dequant", annotate=True) as sp_d:
+    with telemetry.span("decode.dequant") as sp_d:
         prev_dev = jnp.asarray(prev).reshape(-1).astype(cdt)
         centers = jnp.asarray(_centers_lut(step, cdt))
         recon = kops.dequantize(idx, prev_dev, centers, b_bits=step.b_bits,
                                 use_pallas=not kops._interpret())
         if tele:
             jax.block_until_ready(recon)
-    with telemetry.span("decode.patch", annotate=True) as sp_p:
+    with telemetry.span("decode.patch") as sp_p:
         if step.n_incompressible:
             recon = kops.patch_exceptions(recon, idx,
                                           jnp.asarray(step.incomp_values),
@@ -434,7 +433,7 @@ def decompress_step(step: CompressedStep,
         return decode_anchor(step)
     if device_decode_route(step):
         dev = decompress_step_device(step, prev)
-        with telemetry.span("decode.fetch", annotate=True) as sp_f:
+        with telemetry.span("decode.fetch") as sp_f:
             out = np.asarray(dev)
         if telemetry.enabled() and "telemetry_read" in step.meta:
             step.meta["telemetry_read"]["fetch_s"] = sp_f.duration
@@ -443,13 +442,13 @@ def decompress_step(step: CompressedStep,
     tele = telemetry.enabled()
     cdt = pipe.reconstruction_dtype(step.dtype)
     marker = (1 << step.b_bits) - 1
-    with telemetry.span("decode.entropy", annotate=True) as sp_e:
+    with telemetry.span("decode.entropy") as sp_e:
         idx = _decode_index_host(step)
-    with telemetry.span("decode.dequant", annotate=True) as sp_d:
+    with telemetry.span("decode.dequant") as sp_d:
         prev_flat = np.asarray(prev).reshape(-1).astype(cdt, copy=False)
         centers = _centers_lut(step, cdt)
         out = prev_flat * (1 + centers[idx])
-    with telemetry.span("decode.patch", annotate=True) as sp_p:
+    with telemetry.span("decode.patch") as sp_p:
         if step.n_incompressible:
             # Exception values are compacted in stream order == block
             # order, so one global boolean scatter equals the per-block
@@ -570,7 +569,7 @@ class TemporalDecompressor:
     def add(self, step: CompressedStep) -> np.ndarray:
         if not step.is_anchor and device_decode_route(step):
             self._state = decompress_step_device(step, self._state)
-            with telemetry.span("decode.fetch", annotate=True) as sp_f:
+            with telemetry.span("decode.fetch") as sp_f:
                 out = np.asarray(self._state)
             if telemetry.enabled() and "telemetry_read" in step.meta:
                 step.meta["telemetry_read"]["fetch_s"] = sp_f.duration

@@ -870,6 +870,12 @@ class NCKReader:
 
     def read_step(self, name: str) -> CompressedStep:
         """Inverse of NCKWriter.add_step."""
+        with telemetry.span("nck.read", name=name) as sp:
+            step = self._read_step(name)
+            sp.set(bytes=step.nbytes)
+        return step
+
+    def _read_step(self, name: str) -> CompressedStep:
         if self.manifest is not None:
             return self._read_step_merged(name)
         if f"{name}_anchor" in self.variables:

@@ -178,13 +178,14 @@ def pack_blocks_host(idx: np.ndarray, b_bits: int,
     n = idx.size
     if n == 0:
         return []
-    nblocks = -(-n // block_elems)
-    total = nblocks * block_elems
-    padded = idx if total == n else np.concatenate(
-        [idx, np.full(total - n, marker, idx.dtype)])
-    packed = packing.pack_indices_np(padded, b_bits).tobytes()
-    bpb = block_elems * b_bits // 8          # bytes per block (exact)
-    return [packed[s:s + bpb] for s in range(0, nblocks * bpb, bpb)]
+    with telemetry.span("finalize.pack", n=n, b_bits=b_bits):
+        nblocks = -(-n // block_elems)
+        total = nblocks * block_elems
+        padded = idx if total == n else np.concatenate(
+            [idx, np.full(total - n, marker, idx.dtype)])
+        packed = packing.pack_indices_np(padded, b_bits).tobytes()
+        bpb = block_elems * b_bits // 8          # bytes per block (exact)
+        return [packed[s:s + bpb] for s in range(0, nblocks * bpb, bpb)]
 
 
 def exception_offsets(incomp_mask: np.ndarray,

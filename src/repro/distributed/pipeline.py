@@ -343,8 +343,7 @@ class ShardedCompressor:
         analyze = self._analyze_fn(ebytes, n)
         # The b_auto fetch is a device sync point: the analyze span covers
         # dispatch + the wait, so it reads as real stage time.
-        with telemetry.span("encode.analyze", annotate=True,
-                            n=n) as sp_an:
+        with telemetry.span("encode.analyze", n=n) as sp_an:
             (b_auto, ids_desc, counts_desc, domain_lo, width,
              est_sizes) = analyze(prev_dev, curr_dev,
                                   jnp.float32(p.error_bound))
@@ -362,8 +361,7 @@ class ShardedCompressor:
                     "use fewer shards or larger inputs")
 
         encode = self._encode_fn(bb, k_eff, be, ln, n)
-        with telemetry.span("encode.index", annotate=True,
-                            b_bits=bb) as sp_idx:
+        with telemetry.span("encode.index", b_bits=bb) as sp_idx:
             idx_dev, packed, valid = encode(prev_dev, curr_dev,
                                             ids_desc, domain_lo, width)
             if telemetry.enabled():
@@ -378,7 +376,7 @@ class ShardedCompressor:
         nbytes_block = be * bb // 8
         raws = coded = coded_name = None
         sp_pack_s = 0.0
-        with telemetry.span("encode.device_entropy", annotate=True) as sp_de:
+        with telemetry.span("encode.device_entropy") as sp_de:
             if device_entropy_route(p, n, bb):
                 # Entropy-code on the mesh; only emission buffers cross to
                 # host.  The packed words never leave the devices un-coded.
@@ -815,7 +813,7 @@ class ShardedDecompressor:
                 # handles heterogeneous groups -- still device-resident
                 # and bit-identical, just not mesh-sharded.
                 return decompress_step(step, prev)
-        with telemetry.span("decode.entropy", annotate=True) as sp_e:
+        with telemetry.span("decode.entropy") as sp_e:
             if parsed is not None:
                 # Mesh-resident entropy decode: blocks distribute
                 # contiguously over shards, so the flattened output IS
@@ -823,18 +821,21 @@ class ShardedDecompressor:
                 idx_dev = self._rans_decode_stage(step, parsed)
                 ln = idx_dev.shape[1] * step.block_elems
                 idx_dev = idx_dev.reshape(-1)
+                if tele:
+                    jax.block_until_ready(idx_dev)
             else:
                 # host: inflate + unpack (block-parallel over the shared
                 # entropy pool), one upload.
                 idx = comp._decode_index_host(step)
                 ln = -(-n // P_)
                 sharded, _ = self._shardings()
-                idx_dev = jax.device_put(
-                    _pad_to(idx.astype(np.int32), P_ * ln, marker),
-                    sharded)
-            if tele:
-                jax.block_until_ready(idx_dev)
-        with telemetry.span("decode.dequant", annotate=True) as sp_d:
+                with telemetry.span("decode.upload"):
+                    idx_dev = jax.device_put(
+                        _pad_to(idx.astype(np.int32), P_ * ln, marker),
+                        sharded)
+                    if tele:
+                        jax.block_until_ready(idx_dev)
+        with telemetry.span("decode.dequant") as sp_d:
             sharded, rep = self._shardings()
             prev_p = _pad_to(np.asarray(prev, cdt).reshape(-1), P_ * ln,
                              0.0)
@@ -844,7 +845,7 @@ class ShardedDecompressor:
                 jax.device_put(centers, rep)).reshape(-1)
             if tele:
                 jax.block_until_ready(out)
-        with telemetry.span("decode.patch", annotate=True) as sp_p:
+        with telemetry.span("decode.patch") as sp_p:
             # device: scatter the exception table over the marker lanes
             # (the padded tail may also read as marker, but real markers
             # all precede it in stream order, so the table lands exactly
@@ -856,7 +857,7 @@ class ShardedDecompressor:
                     b_bits=step.b_bits)
             if tele:
                 jax.block_until_ready(out)
-        with telemetry.span("decode.fetch", annotate=True) as sp_f:
+        with telemetry.span("decode.fetch") as sp_f:
             res = np.asarray(out)[:n].astype(step.dtype
                                              ).reshape(step.shape)
         if tele:
@@ -971,7 +972,7 @@ class MultiProcessCompressor(ShardedCompressor):
         ebytes = np.dtype(curr_np.dtype).itemsize
 
         analyze = self._analyze_fn(ebytes, n)
-        with telemetry.span("encode.analyze", annotate=True, n=n) as sp_an:
+        with telemetry.span("encode.analyze", n=n) as sp_an:
             (b_auto, ids_desc, counts_desc, domain_lo, width,
              est_sizes) = analyze(prev_dev, curr_dev,
                                   jnp.float32(p.error_bound))
@@ -988,8 +989,7 @@ class MultiProcessCompressor(ShardedCompressor):
                     "use fewer shards or larger inputs")
 
         encode = self._encode_fn(bb, k_eff, be, ln, n)
-        with telemetry.span("encode.index", annotate=True,
-                            b_bits=bb) as sp_idx:
+        with telemetry.span("encode.index", b_bits=bb) as sp_idx:
             idx_dev, packed, valid = encode(prev_dev, curr_dev,
                                             ids_desc, domain_lo, width)
             if telemetry.enabled():

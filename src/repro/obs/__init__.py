@@ -11,11 +11,10 @@ from repro.obs import trace
 from repro.obs import report
 from repro.obs.telemetry import (Registry, capture, counter, enabled, gauge,
                                  histo, span, start, stop)
-from repro.obs.trace import chrome_trace, device_annotation, \
-    write_chrome_trace
+from repro.obs.trace import chrome_trace, write_chrome_trace
 from repro.obs.report import rollup, series_rollup
 
 __all__ = ["telemetry", "trace", "report", "Registry", "capture", "counter",
            "enabled", "gauge", "histo", "span", "start", "stop",
-           "chrome_trace", "device_annotation", "write_chrome_trace",
+           "chrome_trace", "write_chrome_trace",
            "rollup", "series_rollup"]

@@ -32,9 +32,11 @@ Usage::
     report.rollup(reg)          # aggregates
     trace.write_chrome_trace(path, reg)   # chrome://tracing JSON
 
-``span(..., annotate=True)`` additionally enters a
-``jax.profiler.TraceAnnotation`` (registered lazily by ``obs.trace``) so
-host spans line up with device kernels in a jax profiler capture.
+While a capture is active every span, pool-thread spans included, also
+enters the registered annotation factory (``obs.trace`` installs
+``jax.profiler.TraceAnnotation``), so inside a jax profiler capture each
+span is a host event on the profiler's own clock, lined up with the device
+kernels it launched.
 """
 from __future__ import annotations
 
@@ -217,16 +219,14 @@ class Span:
 
     __slots__ = ("_reg", "name", "attrs", "t0", "t1", "_depth", "_ann")
 
-    def __init__(self, reg: Registry, name: str, attrs: Dict[str, Any],
-                 annotate: bool):
+    def __init__(self, reg: Registry, name: str, attrs: Dict[str, Any]):
         self._reg = reg
         self.name = name
         self.attrs = attrs
         self.t0 = 0.0
         self.t1 = 0.0
-        ann = _annotation_factory(name) if (annotate
-                                            and _annotation_factory) else None
-        self._ann = ann
+        fac = _annotation_factory
+        self._ann = fac(name) if fac is not None else None
 
     def set(self, **kw):
         self.attrs.update(kw)
@@ -260,13 +260,14 @@ class Span:
         return False
 
 
-def span(name: str, annotate: bool = False, **attrs):
+def span(name: str, /, **attrs):
     """Open a (nested) span.  Returns the shared no-op constant when
-    telemetry is disabled -- safe to leave in hot paths."""
+    telemetry is disabled -- safe to leave in hot paths.  ``name`` is
+    positional-only, so ``name=`` may be an attribute."""
     reg = _active
     if reg is None:
         return NOOP_SPAN
-    return Span(reg, name, attrs, annotate)
+    return Span(reg, name, attrs)
 
 
 def counter(name: str, value: float = 1.0):

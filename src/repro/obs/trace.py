@@ -17,10 +17,10 @@ Open a written file at chrome://tracing or https://ui.perfetto.dev.
 
 Device bridging: importing this module registers a
 ``jax.profiler.TraceAnnotation`` factory with the telemetry layer, so
-``span(..., annotate=True)`` host spans also appear inside a jax profiler
-capture, lined up with the device kernels they launched.  The import is
-lazy and failure-tolerant -- environments without jax still get host
-spans.
+while a capture is active every host span also appears inside a jax
+profiler capture, on the profiler's clock and lined up with the device
+kernels it launched.  The import is lazy and failure-tolerant --
+environments without jax still get host spans.
 """
 from __future__ import annotations
 
@@ -29,7 +29,7 @@ from typing import Any, Dict, Optional
 
 from repro.obs import telemetry
 
-__all__ = ["chrome_trace", "write_chrome_trace", "device_annotation"]
+__all__ = ["chrome_trace", "write_chrome_trace"]
 
 _PID = 0                    # single-process trace; lanes are threads
 
@@ -45,14 +45,6 @@ def _jax_annotation(name: str):
 
 
 telemetry.set_annotation_factory(_jax_annotation)
-
-
-def device_annotation(name: str):
-    """Standalone device annotation (no host span): a context manager that
-    is a no-op unless telemetry is enabled and jax is importable."""
-    if not telemetry.enabled():
-        return telemetry.NOOP_SPAN
-    return _jax_annotation(name) or telemetry.NOOP_SPAN
 
 
 def chrome_trace(reg: Optional[telemetry.Registry] = None) -> Dict[str, Any]:

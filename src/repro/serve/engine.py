@@ -227,7 +227,7 @@ class Engine:
         """Shared streaming loop of generate/resume (same jitted callable)."""
         out = []
         t0 = time.perf_counter()
-        with telemetry.span("serve.decode_loop", annotate=True,
+        with telemetry.span("serve.decode_loop",
                             max_new=max_new, batch=self.B):
             for i in range(max_new):
                 out.append(np.asarray(tok)[:, 0])
@@ -257,7 +257,7 @@ class Engine:
         """prompts (B, S0) int32 -> (B, max_new) int32 generated tokens."""
         assert prompts.shape[0] == self.B
         t0 = time.perf_counter()
-        with telemetry.span("serve.prefill", annotate=True,
+        with telemetry.span("serve.prefill",
                             batch=self.B, s0=int(prompts.shape[1])):
             logits, cache, pos = self._prefill(
                 self.params, {"tokens": jnp.asarray(prompts)})

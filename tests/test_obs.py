@@ -117,7 +117,7 @@ def test_capture_scoping():
 def test_disabled_returns_shared_noop():
     assert not telemetry.enabled()
     assert telemetry.span("x") is telemetry.NOOP_SPAN
-    assert telemetry.span("y", annotate=True, k=1) is telemetry.NOOP_SPAN
+    assert telemetry.span("y", k=1) is telemetry.NOOP_SPAN
     assert telemetry.NOOP_SPAN.set(a=1) is telemetry.NOOP_SPAN
     assert telemetry.NOOP_SPAN.duration == 0.0
     # counters/gauges/hists fall through without touching a registry
