@@ -7,10 +7,10 @@ place, through the same comparison that decides ``correct``.
 
 A write cell's control needs no program: the traffic of ``--units``
 field-steps is drawn from each seed as a run draws it, and the reference
-at the lower precision compresses the same originals.  A read cell's
-control decodes the archive that set-up made with the program.  Each
-seed prints one JSON line of readings; the benchmark's own runs never run
-this.
+at the lower precision compresses the same originals, made whole on one
+chip.  A read cell's control decodes the archive that set-up made with
+the program.  Each seed prints one JSON line of readings; the
+benchmark's own runs never run this.
 """
 import argparse
 import json
@@ -38,7 +38,7 @@ def readings(cell: dict, config: dict, traffic: dict, seed: int, units: int,
                 fd = load.fields[f]
                 fd.t += 1
                 load.draw_change(f, fd.t)
-                if i == 0:              # the set-up's warm step
+                if i == load.warm_fields() - 1:     # set-up's warm steps
                     load.new_block()
         else:
             load.setup()
@@ -51,6 +51,15 @@ def readings(cell: dict, config: dict, traffic: dict, seed: int, units: int,
                                 "fails": not c.ok} for c in checks}}
 
 
+def placement(cell: dict, config: dict, traffic: dict):
+    """(configuration, chips) of the control: a write control runs no
+    program, so it makes its originals whole on one chip, the values that a
+    mesh of the cell's chips makes (``chipbench/tests/test_mesh.py``)."""
+    if traffic["mode"] != "write":
+        return config, cell["chips"]
+    return dict(config, field_partition=None), 1
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
@@ -61,18 +70,19 @@ def main(argv=None) -> int:
     from chipbench import harness
     bench = harness.load_benchmark(ROOT)
     cell = harness.cell_of(bench, args.workload)
+    traffic = harness.load_traffic(cell["traffic"])
+    config, chips = placement(cell, harness.load_config(cell["config"]),
+                              traffic)
     import jax
     devices = jax.devices()
-    if devices[0].platform != "tpu" or len(devices) < cell["chips"]:
-        print("control.py: needs the cell's chips", file=sys.stderr)
+    if devices[0].platform != "tpu" or len(devices) < chips:
+        print(f"control.py: needs {chips} TPU chips", file=sys.stderr)
         return 2
-    config = harness.load_config(cell["config"])
-    traffic = harness.load_traffic(cell["traffic"])
     workdir = os.path.join(ROOT, ".chipbench", "control", cell["name"])
     for s in args.seeds.split(","):
         t0 = time.monotonic()
         out = readings(cell, config, traffic, int(s), args.units,
-                       devices[:cell["chips"]], workdir, args.precision)
+                       devices[:chips], workdir, args.precision)
         out["seconds"] = time.monotonic() - t0
         out["host_peak_rss_bytes"] = resource.getrusage(
             resource.RUSAGE_SELF).ru_maxrss * 1024

@@ -9,11 +9,17 @@ milliseconds instead of seconds.
 A step is ``curr = prev * change``: ``change`` is one field of a small
 pool of spectral change fields made in set-up, overridden by the static
 cells (fixed per field) and by jumps drawn anew for every step.
+
+Given a sharding, every field is made with it as its output sharding, so
+that each chip of a mesh holds its own part; a step is elementwise and
+stays on its shards.  The spectral filter of anchors and change fields
+(set-up only) runs whole on every chip, which then keeps its part: the
+values are those of the unsharded generator.
 """
 from __future__ import annotations
 
 from functools import partial
-from typing import Sequence
+from typing import Iterator, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -31,9 +37,13 @@ def stream_key(key: jax.Array, stream: int, index: int) -> jax.Array:
     return jax.random.fold_in(jax.random.fold_in(key, stream), index)
 
 
-def correlated_field(key, shape: Sequence[int], slope: float) -> jax.Array:
-    """Unit-variance random field with a power-law spectrum (FFT filter)."""
+def correlated_field(key, shape: Sequence[int], slope: float,
+                     whole=None) -> jax.Array:
+    """Unit-variance random field with a power-law spectrum (FFT filter);
+    ``whole``, a replicated sharding, makes every chip filter all of it."""
     white = jax.random.normal(key, shape, jnp.float32)
+    if whole is not None:
+        white = jax.lax.with_sharding_constraint(white, whole)
     f = jnp.fft.rfftn(white)
     k2 = jnp.zeros((), jnp.float32)
     for d, n in enumerate(shape):
@@ -44,15 +54,17 @@ def correlated_field(key, shape: Sequence[int], slope: float) -> jax.Array:
         k2 = k2 + fr.reshape(dims) ** 2
     k = jnp.sqrt(k2).at[(0,) * len(shape)].set(1.0)
     out = jnp.fft.irfftn(f * k ** slope, s=tuple(shape))
+    if whole is not None:
+        out = jax.lax.with_sharding_constraint(out, whole)
     return (out - out.mean()) / (out.std() + 1e-9)
 
 
-def _anchor(key, *, shape, slope, offset, dtype):
-    return (correlated_field(key, shape, slope) + offset).astype(dtype)
+def _anchor(key, *, shape, slope, offset, dtype, whole):
+    return (correlated_field(key, shape, slope, whole) + offset).astype(dtype)
 
 
-def _change(key, *, shape, slope, vol):
-    return 1.0 + vol * correlated_field(key, shape, slope)
+def _change(key, *, shape, slope, vol, whole):
+    return 1.0 + vol * correlated_field(key, shape, slope, whole)
 
 
 def _step(prev, change, kstatic, kstep, t, *, static_frac, jump_frac):
@@ -71,19 +83,28 @@ class FieldGen:
     """Anchors, change fields and steps of one field shape, on the device.
 
     ``stats`` holds ``vol``, ``jump_frac``, ``static_frac``, ``offset`` and
-    ``slope`` as in ``repro.data.temporal.TemporalFieldSpec``.
+    ``slope`` as in ``repro.data.temporal.TemporalFieldSpec``.  ``sharding``
+    (None: the default device) places every field it makes.
     """
 
-    def __init__(self, shape: Sequence[int], dtype: str, stats: dict):
+    def __init__(self, shape: Sequence[int], dtype: str, stats: dict,
+                 sharding: Optional[jax.sharding.NamedSharding] = None):
         self.shape = tuple(int(x) for x in shape)
         self.dtype = jnp.dtype(dtype)
         s = stats
-        self._anchor = jax.jit(partial(
+        if sharding is None:
+            jit, whole = jax.jit, None
+        else:
+            jit = partial(jax.jit, out_shardings=sharding)
+            whole = jax.sharding.NamedSharding(sharding.mesh,
+                                               jax.sharding.PartitionSpec())
+        self._anchor = jit(partial(
             _anchor, shape=self.shape, slope=s["slope"], offset=s["offset"],
-            dtype=self.dtype))
-        self._change = jax.jit(partial(
-            _change, shape=self.shape, slope=s["slope"], vol=s["vol"]))
-        self._step = jax.jit(partial(
+            dtype=self.dtype, whole=whole))
+        self._change = jit(partial(
+            _change, shape=self.shape, slope=s["slope"], vol=s["vol"],
+            whole=whole))
+        self._step = jit(partial(
             _step, static_frac=s["static_frac"], jump_frac=s["jump_frac"]))
 
     def anchor(self, key, field: int) -> jax.Array:
@@ -97,10 +118,11 @@ class FieldGen:
         return self._step(prev, change, stream_key(key, _STATIC, field),
                           stream_key(key, _STEP, field), jnp.int32(t))
 
-    def series(self, key, field: int, pool, pool_index) -> list:
-        """Steps 0..len(pool_index) of one field: ``pool_index[t - 1]``
-        names the change field of step ``t``."""
-        out = [self.anchor(key, field)]
+    def series(self, key, field: int, pool, pool_index) -> Iterator:
+        """Steps 0..len(pool_index) of one field, one at a time:
+        ``pool_index[t - 1]`` names the change field of step ``t``."""
+        curr = self.anchor(key, field)
+        yield curr
         for t, j in enumerate(pool_index, start=1):
-            out.append(self.step(out[-1], pool[j], key, field, t))
-        return out
+            curr = self.step(curr, pool[j], key, field, t)
+            yield curr

@@ -4,11 +4,13 @@ system under test through set-up, the measured window and the check.
 Modes (the ``mode`` of a traffic file):
 
 ``write``  Streaming output of a simulation.  Every field of the
-           configuration gets its own compressor; the window goes
+           configuration gets its own compressor (the driver the
+           configuration names, over the cell's chips); the window goes
            round-robin over the fields, one writer, closed loop: make the
-           field's next step on the device, ``add`` it, write the step to
-           its own NCK file (fsynced).  A unit is one field-step made
-           durable.
+           field's next step on the device (on the mesh, each chip its
+           part, where the configuration names a partition), ``add`` it,
+           write the step to its own NCK file (fsynced).  A unit is one
+           field-step made durable.
 ``read``   A reader restoring one field's series.  Set-up compresses and
            archives the series with the program; the window opens the
            archive and restores its steps in order through the reader the
@@ -29,7 +31,7 @@ import time
 import zlib
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Dict, Iterator, List, Optional
 
 import numpy as np
 
@@ -43,21 +45,48 @@ def _params(config: dict):
     return NumarckParams(**config.get("params", {}))
 
 
-def make_driver(config: dict):
-    """The system under test, as the configuration names it."""
-    if config["driver"] != "TemporalCompressor":
-        raise ValueError(f"unknown driver {config['driver']!r}")
-    from repro.core import TemporalCompressor
-    return TemporalCompressor(_params(config))
+def _mesh(devices, axis: str):
+    from jax.sharding import Mesh
+    return Mesh(np.array(devices), (axis,))
+
+
+def make_driver(config: dict, devices):
+    """The system under test, as the configuration names it, over the
+    cell's chips."""
+    if config["driver"] == "TemporalCompressor":
+        from repro.core import TemporalCompressor
+        return TemporalCompressor(_params(config))
+    if config["driver"] == "ShardedCompressor":
+        from repro.distributed.pipeline import ShardedCompressor
+        axis = config["mesh_axis"]
+        return ShardedCompressor(_mesh(devices, axis), axis, _params(config))
+    raise ValueError(f"unknown driver {config['driver']!r}")
+
+
+# Drivers whose compiled programs every instance shares: one warm step
+# compiles them for all fields.  Any other driver compiles per instance,
+# so every field warms its own.
+SHARED_PROGRAMS = {"TemporalCompressor"}
+
+
+def field_sharding(config: dict, devices):
+    """Where each field lives: its ``field_partition`` (one mesh axis name
+    or null per dimension) over a mesh of the cell's chips, or None for
+    the default device."""
+    spec = config.get("field_partition")
+    if spec is None:
+        return None
+    from jax.sharding import NamedSharding, PartitionSpec
+    return NamedSharding(_mesh(devices, config["mesh_axis"]),
+                         PartitionSpec(*spec))
 
 
 def make_reader(traffic: dict, devices):
     """The decompressor a read mix names, over a mesh of the cell's chips."""
     if traffic["reader"] != "ShardedDecompressor":
         raise ValueError(f"unknown reader {traffic['reader']!r}")
-    from jax.sharding import Mesh
     from repro.distributed.pipeline import ShardedDecompressor
-    return ShardedDecompressor(Mesh(np.array(devices), ("data",)), "data")
+    return ShardedDecompressor(_mesh(devices, "data"), "data")
 
 
 @dataclass
@@ -87,7 +116,8 @@ class Load:
         self.rng = np.random.default_rng(self.seed)
         self.key = datagen.seed_key(self.seed)
         self.gen = datagen.FieldGen(config["shape"], config["dtype"],
-                                    config["stats"])
+                                    config["stats"],
+                                    field_sharding(config, self.devices))
         # The change fields are the same for every seed; the seed orders
         # them.  How well a step compresses depends mostly on its change
         # field, so a pool drawn from the seed would make the seed change
@@ -114,31 +144,52 @@ class Load:
         """Start the next draw on a fresh block."""
         self._block = []
 
-    def originals(self, f: int, t_last: int) -> List[np.ndarray]:
-        """Steps 0..t_last of field ``f`` made again from the seed."""
+    def originals(self, f: int, t_last: int) -> Iterator[np.ndarray]:
+        """Steps 0..t_last of field ``f`` made again from the seed, by the
+        window's generator, one at a time on the host."""
         idx = [self.pool_index[(f, t)] for t in range(1, t_last + 1)]
-        return [np.asarray(a) for a in self.gen.series(self.key, f,
-                                                        self.pool, idx)]
+        for a in self.gen.series(self.key, f, self.pool, idx):
+            yield np.asarray(a)
 
 
 class WriteLoad(Load):
     mode = "write"
 
+    def warm_fields(self) -> int:
+        """Fields that make one recorded delta step in set-up: the first
+        where the compressors share their programs, else every one."""
+        if self.config["driver"] in SHARED_PROGRAMS:
+            return 1
+        return len(self.config["fields"])
+
     def setup(self) -> None:
         import jax
+        t0 = time.perf_counter()
         self.fields = [_Field(name) for name in self.config["fields"]]
         for f, fd in enumerate(self.fields):
-            fd.comp = make_driver(self.config)
+            fd.comp = make_driver(self.config, self.devices)
             fd.prev = self.gen.anchor(self.key, f)
             fd.files = {}
             fd.comp.add(fd.prev)
-        # One recorded delta step of the first field compiles every program
-        # a delta step runs (the compressors share them); the check reads
-        # its file like any other.
-        unit = self._step(0, self.fields[0])
-        warm_nonzero(self.n, unit.exceptions)
+        t1 = time.perf_counter()
+        # Recorded delta steps compile every program a delta step runs;
+        # the check reads their files like any other.
+        warm = [self._step(f, self.fields[f])
+                for f in range(self.warm_fields())]
+        t2 = time.perf_counter()
+        if self.config["driver"] in SHARED_PROGRAMS:
+            warm_nonzero(self.n, warm[0].exceptions)
+        else:
+            warm_exceptions(self.devices, self.config["mesh_axis"], self.n,
+                            [u.exceptions for u in warm], self.block_elems,
+                            warm[0].b_bits)
         self.new_block()
         jax.block_until_ready([fd.prev for fd in self.fields])
+        print(f"set-up stages (s): anchors {t1 - t0:.3f}, warm steps "
+              f"{t2 - t1:.3f}, exception sizes {time.perf_counter() - t2:.3f}"
+              "; warm steps (field, B, exceptions): " + json.dumps(
+                  [[u.field, u.b_bits, u.exceptions] for u in warm]),
+              flush=True)
 
     def _step(self, f: int, fd: _Field) -> Unit:
         from repro.core.container import NCKWriter
@@ -162,6 +213,7 @@ class WriteLoad(Load):
         t1 = time.perf_counter()
         fd.prev, fd.t = curr, t
         fd.files[t] = path
+        self.block_elems = step.block_elems
         return Unit(t0, t1, self.unit_bytes, os.path.getsize(path),
                     step.b_bits, f, t, step.n_incompressible)
 
@@ -171,7 +223,7 @@ class WriteLoad(Load):
         field equally often."""
         units: List[Unit] = []
         end = time.perf_counter() + seconds
-        i = 1                       # field 0 made its first step in set-up
+        i = self.warm_fields()      # they made their first step in set-up
         while not units or units[-1].t1 < end or len(units) % len(self.pool):
             f = i % len(self.fields)
             units.append(self._step(f, self.fields[f]))
@@ -193,24 +245,25 @@ class WriteLoad(Load):
         ``candidate(f, t, prev_recon, original)`` stands in for reading the
         program's file (the control passes the reference at a lower
         precision); it returns the reconstruction.  ``workers`` threads
-        check fields at once (default: half the host's cores)."""
+        check fields at once, each making its field's originals as it goes
+        (default: half the host's cores, and no more than
+        ``CHECK_HOST_BYTES`` hold)."""
         work = [(f, fd) for f, fd in enumerate(self.fields) if fd.t]
-        series = {f: self.originals(f, fd.t) for f, fd in work}
         ulps = int(self.traffic["mismatch_ulps"])
 
         def one(item):
             f, fd = item
-            D = series[f]
-            got = D[0]                  # the anchor is stored losslessly
+            originals = self.originals(f, fd.t)
+            got = next(originals)       # the anchor is stored losslessly
             out = []
-            for t in range(1, fd.t + 1):
+            for t, curr in enumerate(originals, start=1):
                 try:
                     if candidate is None:
                         name = os.path.basename(fd.files[t])[:-len(".nck")]
                         new = ref.decode(ref.NCKFile(fd.files[t]), name,
                                          got)[0]
                     else:
-                        new = candidate(f, t, got, D[t])
+                        new = candidate(f, t, got, curr)
                 except (ValueError, KeyError, zlib.error) as e:
                     # A file the reference cannot read back holds no
                     # right element; the chain of this field ends here.
@@ -218,13 +271,15 @@ class WriteLoad(Load):
                           flush=True)
                     out.append((1.0, 0.0))
                     break
-                want = ref.compress_step(got, D[t], self.error_bound)[0]
+                want = ref.compress_step(got, curr, self.error_bound)[0]
                 out.append((mismatch(new, want, ulps),
-                            bound_ratio(D[t], new, got, self.error_bound)))
+                            bound_ratio(curr, new, got, self.error_bound)))
                 got = new
             return out
 
-        workers = workers or (os.cpu_count() or 2) // 2
+        workers = workers or min((os.cpu_count() or 2) // 2,
+                                 CHECK_HOST_BYTES // (CHECK_BYTES_PER_ELEMENT
+                                                      * self.n))
         workers = max(1, min(len(work), workers))
         with ThreadPoolExecutor(workers) as ex:
             rows = [r for rs in ex.map(one, work) for r in rs]
@@ -345,6 +400,14 @@ class ReadLoad(Load):
         return self.check(ref.rounder(precision))
 
 
+# Host memory the write check's fields may hold at once, and what one
+# field holds per element at its peak: the previous reconstruction, the
+# original, the program's and the reference's reconstructions, and the
+# reference's ratios, bins and index.
+CHECK_HOST_BYTES = 16 << 30
+CHECK_BYTES_PER_ELEMENT = 40
+
+
 def mismatch(got: np.ndarray, want: np.ndarray, ulps: int) -> float:
     """Share of elements whose float32 values lie more than ``ulps`` units
     in the last place apart (0: the bits differ)."""
@@ -352,7 +415,12 @@ def mismatch(got: np.ndarray, want: np.ndarray, ulps: int) -> float:
     w = np.asarray(want, np.float32).reshape(-1)
     if g.size != w.size:
         return 1.0
-    return float(np.count_nonzero(ulp_distance(g, w) > ulps)) / max(g.size, 1)
+
+    def part(s):
+        differ = g[s].view(np.int32) != w[s].view(np.int32)
+        return np.count_nonzero(ulp_distance(g[s][differ], w[s][differ])
+                                > ulps)
+    return float(sum(ref.chunked(part, g.size))) / max(g.size, 1)
 
 
 def ulp_distance(a: np.ndarray, b: np.ndarray) -> np.ndarray:
@@ -362,6 +430,32 @@ def ulp_distance(a: np.ndarray, b: np.ndarray) -> np.ndarray:
         i = x.view(np.int32).astype(np.int64)
         return np.where(i < 0, -(i & 0x7FFFFFFF), i)
     return np.abs(line(a) - line(b))
+
+
+def warm_exceptions(devices, axis: str, n: int, counts: List[int],
+                    block_elems: int, b_bits: int) -> None:
+    """The sharded encode compacts a step's exceptions from its index as
+    the mesh holds it, padded to the next power of two of their count
+    (``kops.exception_compact``), so a step whose count crosses one
+    compiles.  Run that compaction on an index of the same layout for the
+    sizes of counts within a factor of four of each warm step's."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+    from repro.kernels import ops as kops
+    shards = len(devices)
+    ln = -(-n // shards)
+    marker = (1 << b_bits) - 1
+    make = jax.jit(lambda c: jnp.where(
+        jnp.arange(shards * ln, dtype=jnp.int32).reshape(shards, ln) < c,
+        jnp.int32(marker), jnp.int32(0)),
+        out_shardings=NamedSharding(_mesh(devices, axis),
+                                    PartitionSpec(axis)))
+    sizes = {min(1 << (c - 1).bit_length(), n) for k in counts
+             for c in (k // 4, k // 2, k, 2 * k, 4 * k) if c > 0}
+    for m in sorted(sizes):
+        kops.exception_compact(make(jnp.int32(m)).reshape(-1), n, marker,
+                               block_elems)
 
 
 def warm_nonzero(n: int, k: int) -> None:
@@ -379,14 +473,20 @@ def warm_nonzero(n: int, k: int) -> None:
 def bound_ratio(orig, recon, prev, error_bound: float) -> float:
     """Worst |D - R| / (E |R_prev|): at most 1 for an element stored as a
     bin, 0 for one stored as it is."""
-    d = np.asarray(orig, np.float64).reshape(-1)
-    r = np.asarray(recon, np.float64).reshape(-1)
-    p = np.abs(np.asarray(prev, np.float64).reshape(-1))
-    keep = p > 0
-    if not keep.any():
-        return 0.0
-    return float(np.max(np.abs(d[keep] - r[keep])
-                        / (error_bound * p[keep])))
+    d = np.asarray(orig).reshape(-1)
+    r = np.asarray(recon).reshape(-1)
+    p = np.asarray(prev).reshape(-1)
+
+    def part(s):
+        ps = np.abs(p[s].astype(np.float64))
+        keep = ps > 0
+        if not keep.any():
+            return None
+        return np.max(np.abs(d[s][keep].astype(np.float64)
+                             - r[s][keep].astype(np.float64))
+                      / (error_bound * ps[keep]))
+    worst = [x for x in ref.chunked(part, d.size) if x is not None]
+    return float(np.max(worst)) if worst else 0.0
 
 
 LOADS = {"write": WriteLoad, "read": ReadLoad}

@@ -14,13 +14,20 @@ It imports nothing of the program.  Two parts:
 
 ``rnd`` rounds every intermediate to the working precision: float32
 (the configuration's) for the reference, bfloat16 for the control.
+
+Elementwise work runs in chunks on a pool of threads (NumPy releases the
+interpreter's lock inside its loops); every reduction over chunks (a
+minimum, a maximum, a sum of counts) is exact, so the result is the same
+as one pass over the whole field.
 """
 from __future__ import annotations
 
 import json
+import os
 import struct
 import zlib
-from typing import Callable, Dict, Tuple
+from concurrent.futures import ThreadPoolExecutor
+from typing import Callable, Dict, List, Tuple
 
 import numpy as np
 
@@ -42,6 +49,26 @@ def rounder(precision: str) -> Rounder:
 
 
 F32 = rounder("float32")
+
+# Elements per task, and threads: what the tasks hold at once (about 50
+# bytes an element) stays under 1 GB whatever the host's core count.
+CHUNK = 1 << 20
+THREADS = min(16, len(os.sched_getaffinity(0)))
+_POOL: List[ThreadPoolExecutor] = []
+
+
+def pool() -> ThreadPoolExecutor:
+    """The threads the reference shares; a task never waits on another."""
+    if not _POOL:
+        _POOL.append(ThreadPoolExecutor(THREADS,
+                                        thread_name_prefix="reference"))
+    return _POOL[0]
+
+
+def chunked(fn: Callable[[slice], object], n: int) -> list:
+    """``fn`` of each chunk of ``range(n)``, run on the shared pool."""
+    return list(pool().map(fn, [slice(a, min(a + CHUNK, n))
+                                for a in range(0, n, CHUNK)]))
 
 
 # ---------------------------------------------------------------- container
@@ -98,23 +125,50 @@ def _inflate(blob: bytes, codec: str) -> bytes:
 def _blocks(nck: NCKFile, table: str, offsets: str, codec: str) -> bytes:
     data = nck.bytes(table)
     offs = nck.array(offsets)
-    return b"".join(_inflate(data[offs[i]:offs[i + 1]], codec)
-                    for i in range(len(offs) - 1))
+    return b"".join(pool().map(
+        lambda i: _inflate(data[offs[i]:offs[i + 1]], codec),
+        range(len(offs) - 1)))
 
 
 def unpack(stream: bytes, n: int, b_bits: int) -> np.ndarray:
-    """First ``n`` values of an LSB-first stream of ``b_bits``-bit fields."""
-    bits = np.unpackbits(np.frombuffer(stream, np.uint8), bitorder="little")
-    bits = bits[:n * b_bits].reshape(n, b_bits)
-    width = -(-b_bits // 8) * 8
-    if width != b_bits:
-        bits = np.concatenate(
-            [bits, np.zeros((n, width - b_bits), np.uint8)], axis=1)
-    byts = np.packbits(bits, axis=1, bitorder="little")
-    out = np.zeros(n, np.int64)
-    for i in range(byts.shape[1]):
-        out |= byts[:, i].astype(np.int64) << (8 * i)
-    return out.astype(np.int32)
+    """First ``n`` values of an LSB-first stream of ``b_bits``-bit fields.
+
+    Eight fields fill ``b_bits`` whole bytes, so the stream is read as rows
+    of ``b_bits`` bytes: field ``j`` of a row starts at bit ``j * b_bits``
+    and is assembled from the bytes it covers."""
+    if len(stream) * 8 < n * b_bits:
+        raise ValueError(f"index stream of {len(stream)} bytes holds fewer "
+                         f"than {n} fields of {b_bits} bits")
+    rows = -(-n // 8)
+    buf = np.zeros(rows * b_bits, np.uint8)
+    raw = np.frombuffer(stream, np.uint8)[:buf.size]
+    buf[:raw.size] = raw
+    buf = buf.reshape(rows, b_bits)
+    out = np.empty((rows, 8), np.int32)
+    mask = np.uint32((1 << b_bits) - 1)
+
+    def part(s):
+        for j in range(8):
+            first = j * b_bits
+            v = np.zeros(s.stop - s.start, np.uint32)
+            for k in range(first >> 3, ((first + b_bits - 1) >> 3) + 1):
+                v |= buf[s, k].astype(np.uint32) << np.uint32(
+                    8 * (k - (first >> 3)))
+            out[s, j] = (v >> np.uint32(first & 7)) & mask
+    chunked(part, rows)
+    return out.reshape(-1)[:n]
+
+
+def _dequantize(prev: np.ndarray, idx: np.ndarray, lut: np.ndarray,
+                rnd: Rounder) -> np.ndarray:
+    """``prev * (1 + lut[idx])`` at the working precision, flat."""
+    prev = prev.reshape(-1)
+    out = np.empty(idx.size, np.float32)
+
+    def part(s):
+        out[s] = rnd(rnd(prev[s]) * rnd(1 + lut[idx[s]]))
+    chunked(part, idx.size)
+    return out
 
 
 def decode(nck: NCKFile, name: str, prev, rnd: Rounder = F32):
@@ -137,7 +191,7 @@ def decode(nck: NCKFile, name: str, prev, rnd: Rounder = F32):
     centers = rnd(nck.array(f"{name}_bin_centers").astype(np.float32))
     lut = np.zeros(marker + 1, np.float32)
     lut[:centers.size] = centers
-    out = rnd(rnd(prev).reshape(-1) * rnd(1 + lut[idx]))
+    out = _dequantize(np.asarray(prev), idx, lut, rnd)
     exc = idx == marker
     values = nck.array(f"{name}_incompressible_table")
     if values.size != int(exc.sum()):
@@ -164,38 +218,56 @@ def encode(prev, curr, error_bound: float, max_bins: int = 1 << 16,
            b_max: int = 16, rnd: Rounder = F32
            ) -> Tuple[int, np.ndarray, np.ndarray]:
     """(B, centers, index table) of ``curr`` against ``prev``."""
-    prev = rnd(prev).reshape(-1)
-    curr = rnd(curr).reshape(-1)
-    nonzero = prev != 0
-    safe = np.where(nonzero, prev, np.float32(1))
-    with np.errstate(all="ignore"):
-        r = rnd(rnd(curr - safe) / safe)
-    valid = nonzero & np.isfinite(r) & np.isfinite(curr)
-    r = np.where(valid, r, np.float32(0))
-    if valid.any():
-        lo, hi = r[valid].min(), r[valid].max()
+    prev = np.asarray(prev).reshape(-1)
+    curr = np.asarray(curr).reshape(-1)
+    n = curr.size
+    r = np.empty(n, np.float32)
+    valid = np.empty(n, bool)
+
+    def ratios(s):
+        p, c = rnd(prev[s]), rnd(curr[s])
+        nonzero = p != 0
+        safe = np.where(nonzero, p, np.float32(1))
+        with np.errstate(all="ignore"):
+            rs = rnd(rnd(c - safe) / safe)
+        v = nonzero & np.isfinite(rs) & np.isfinite(c)
+        r[s] = np.where(v, rs, np.float32(0))
+        valid[s] = v
+        return (rs[v].min(), rs[v].max()) if v.any() else None
+    ranges = [x for x in chunked(ratios, n) if x is not None]
+    if ranges:
+        lo, hi = min(x[0] for x in ranges), max(x[1] for x in ranges)
     else:
         lo = hi = np.float32(0)
     width = rnd(np.float32(2.0 * error_bound))
     coverage = rnd(width * np.float32(max_bins))
     domain_lo = lo if rnd(hi - lo) <= coverage else rnd(-0.5 * coverage)
-    with np.errstate(all="ignore"):
-        raw = np.floor(rnd(rnd(r - domain_lo) / width))
-    ok = valid & (raw >= 0) & (raw < max_bins)
-    ids = np.where(ok, raw, -1).astype(np.int64)
-    counts = np.bincount(ids[ok], minlength=max_bins)
+    ids = np.empty(n, np.int32)         # candidate bin, -1 outside
+
+    def bins(s):
+        with np.errstate(all="ignore"):
+            raw = np.floor(rnd(rnd(r[s] - domain_lo) / width))
+        ok = valid[s] & (raw >= 0) & (raw < max_bins)
+        ids[s] = np.where(ok, raw, -1)
+        return np.bincount(ids[s][ok], minlength=max_bins)
+    counts = np.sum(chunked(bins, n), axis=0)
     order = np.argsort(-counts, kind="stable")
-    b_bits = int(np.argmin(file_sizes(counts[order], r.size,
-                                      curr.itemsize, b_max))) + 1
+    b_bits = int(np.argmin(file_sizes(counts[order], n, r.itemsize,
+                                      b_max))) + 1
     k = min((1 << b_bits) - 1, max_bins)
     marker = (1 << b_bits) - 1
     sel = order[:k]
     centers = rnd(np.float64(domain_lo)
                   + (sel.astype(np.float64) + 0.5) * np.float64(width))
-    lut = np.full(max_bins, marker, np.int64)
+    lut = np.full(max_bins, marker, np.int32)
     lut[sel] = np.arange(k)
-    idx = np.where(ids >= 0, lut[np.clip(ids, 0, max_bins - 1)], marker)
-    return b_bits, centers, idx.astype(np.int32)
+    idx = np.empty(n, np.int32)
+
+    def index(s):
+        i = ids[s]
+        idx[s] = np.where(i >= 0, lut[np.clip(i, 0, max_bins - 1)], marker)
+    chunked(index, n)
+    return b_bits, centers, idx
 
 
 def reconstruct(prev, curr, b_bits: int, centers: np.ndarray,
@@ -204,9 +276,9 @@ def reconstruct(prev, curr, b_bits: int, centers: np.ndarray,
     marker = (1 << b_bits) - 1
     lut = np.zeros(marker + 1, np.float32)
     lut[:centers.size] = centers
-    out = rnd(rnd(prev).reshape(-1) * rnd(1 + lut[idx]))
+    out = _dequantize(np.asarray(prev), idx, lut, rnd)
     exc = idx == marker
-    out[exc] = rnd(curr).reshape(-1)[exc]
+    out[exc] = rnd(np.asarray(curr).reshape(-1)[exc])
     return out.reshape(np.shape(curr))
 
 

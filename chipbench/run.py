@@ -40,6 +40,10 @@ def log(msg: str) -> None:
     print(msg, flush=True)
 
 
+def peak_rss() -> int:
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+
+
 def end_to_end(mode: str, units, t_start: float, setup_s: float) -> dict:
     """Rates over all the work and all the time of the window: it ends at
     the completion of its last unit."""
@@ -81,6 +85,7 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
     spans = harness.Spans(annotate=trace)
     workdir = os.path.join(STATE, "work", cell["name"])
     load = loadgen.make_load(config, traffic, seed, devices, workdir, spans)
+    log(f"data made {time.monotonic() - start:.3f} s after start")
     try:
         load.setup()
         watch = CompileWatch()
@@ -113,6 +118,7 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
             log("B, exceptions and stored bytes per unit: " + json.dumps(
                 [[u.b_bits, u.exceptions, u.stored] for u in units]))
         log(f"compiles in the window: {dict(watch.events) or 0}")
+        log(f"host peak RSS bytes before the check: {peak_rss()}")
         device = harness.device_info(devices)
         device["memory_peak_bytes"] = harness.memory_peak(devices)
         log(f"peak device bytes: {device['memory_peak_bytes']}")
@@ -146,8 +152,7 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
                                                     "end_to_end")}
         load.free()
         checks = load.check()
-        log("host peak RSS bytes: " + str(resource.getrusage(
-            resource.RUSAGE_SELF).ru_maxrss * 1024))
+        log(f"host peak RSS bytes: {peak_rss()}")
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
     correct = all(c.ok for c in checks)

@@ -87,7 +87,9 @@ def test_every_metric_of_a_cell_reads_a_number(mode, cell):
     trace = tracing.DeviceTrace.from_events(ev)
     spans = [_Span(name, 1e-6, 2e-6) for name in (
         "encode.analyze", "encode.index", "finalize", "nck.write",
-        "decode.entropy", "decode.dequant")]
+        "decode.entropy", "decode.dequant", "finalize.pack",
+        "entropy.compress", "decode.inflate", "decode.unpack",
+        "decode.upload", "nck.read")]
     units = [harness.Unit(0.0, 5e-6, 4 * n, 400, 5, 0, 1)]
     bench = [harness.BenchSpan("bench.add", 0.0, 4e-6, 1)]
     ctx = harness.Ctx(mode, units, spans, bench, trace, lambda t: t * 1e9,
