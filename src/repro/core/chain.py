@@ -138,9 +138,13 @@ class HostReferenceChain(ReferenceChain):
         self.seed(arr)
 
     def advance(self, dev: pipe.DeviceEncoded, curr) -> None:
+        # Exception values the driver gathered on the device spare a host
+        # copy of ``curr``; they are the same values.
+        exc = dev.enc.exc_values
         self._state = pipe.reconstruct_from_indices(
             self._state, dev.enc, dev.centers, self._state.dtype,
-            curr=np.asarray(curr))
+            incomp_values=exc,
+            curr=None if exc is not None else np.asarray(curr))
 
     def to_host(self) -> np.ndarray:
         # A writable *copy*: chains treat state as immutable (fork()

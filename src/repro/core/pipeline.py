@@ -96,7 +96,9 @@ class EncodedIndices:
     ``exc_positions``/``exc_block_counts`` carry the device-computed
     exception compaction (kernels.ops.exception_compact): finalize
     gathers the incompressible values by position instead of re-scanning
-    the full index table with a host boolean mask.
+    the full index table with a host boolean mask.  A driver that also
+    gathered the values on the device sets ``exc_values`` with the
+    step's ``dtype`` and ``shape``; finalize then never reads ``curr``.
     """
 
     # (n,) int32 bin ranks, marker = 2**B - 1.  May be None when the
@@ -119,6 +121,12 @@ class EncodedIndices:
     # marker counts (int64).  None => finalize falls back to the host scan.
     exc_positions: Optional[np.ndarray] = None
     exc_block_counts: Optional[np.ndarray] = None
+    # The values at ``exc_positions`` in the source dtype, gathered on the
+    # device, with the source array's dtype and shape.  None => finalize
+    # reads them from ``curr``.
+    exc_values: Optional[np.ndarray] = None
+    dtype: Optional[np.dtype] = None
+    shape: Optional[Tuple[int, ...]] = None
 
     @property
     def marker(self) -> int:
@@ -232,13 +240,19 @@ def finalize_step(curr: np.ndarray, enc: EncodedIndices,
 
     Exceptions: when the encode stage compacted them on device
     (``enc.exc_positions``), finalize gathers the k values by position --
-    the full index table is never re-scanned here.  Entropy: when the
+    the full index table is never re-scanned here; when it gathered the
+    values too (``enc.exc_values``), ``curr`` is not read and may be
+    None.  Entropy: when the
     encode stage already entropy-coded the blocks on device
     (``enc.entropy_coded``), finalize consumes the blobs as-is; otherwise
     the host codec stage runs, per-block adaptive under ``codec="auto"``
     (a codec id per block, persisted by the NCK container).
     """
-    curr = np.asarray(curr)
+    if enc.exc_values is None:
+        curr = np.asarray(curr)
+        dtype, shape = curr.dtype, tuple(curr.shape)
+    else:
+        dtype, shape = np.dtype(enc.dtype), tuple(enc.shape)
     n = int(enc.n if enc.n is not None else enc.idx.size)
     # Driver-side stage timings (encode_device/_device_encode attach them
     # when telemetry is enabled); never persisted into blob bytes -- the
@@ -248,7 +262,9 @@ def finalize_step(curr: np.ndarray, enc: EncodedIndices,
     with telemetry.span("finalize", n=n, b_bits=enc.b_bits) as sp_fin:
         with telemetry.span("finalize.exceptions") as sp_exc:
             if enc.exc_positions is not None:
-                incomp_values = curr.reshape(-1)[enc.exc_positions]
+                incomp_values = (
+                    enc.exc_values if enc.exc_values is not None
+                    else curr.reshape(-1)[enc.exc_positions])
                 incomp_off = np.concatenate(
                     [[0],
                      np.cumsum(enc.exc_block_counts)])[:-1].astype(np.int64)
@@ -295,7 +311,7 @@ def finalize_step(curr: np.ndarray, enc: EncodedIndices,
                         raws, codec=codec, level=params.zlib_level,
                         parallel=params.parallel_entropy)
             sp_ent.set(codec=codec, blocks=len(blks))
-        centers = round_centers(centers, curr.dtype)
+        centers = round_centers(centers, dtype)
         if centers.size > enc.marker:
             centers = centers[:enc.marker]
         ratio = entropy_ratio(blks, raw_sizes)
@@ -324,7 +340,7 @@ def finalize_step(curr: np.ndarray, enc: EncodedIndices,
             "device_entropy": device_entropy,
         }
     return CompressedStep(
-        n=n, shape=tuple(curr.shape), dtype=str(curr.dtype),
+        n=n, shape=shape, dtype=str(dtype),
         b_bits=enc.b_bits, error_bound=params.error_bound,
         strategy=params.strategy, reference=params.reference,
         domain_lo=float(domain_lo), bin_width=float(width),

@@ -39,7 +39,7 @@ from __future__ import annotations
 
 from collections import deque
 from concurrent.futures import Future
-from functools import partial
+from functools import partial, update_wrapper
 from typing import Deque, Dict, List, Optional, Tuple
 
 import jax
@@ -80,6 +80,39 @@ def _put_sharded(arr: np.ndarray, sharding):
     arr = np.asarray(arr)
     return jax.make_array_from_callback(arr.shape, sharding,
                                         lambda idx: arr[idx])
+
+
+def _relayout(x, *, total):
+    """A field as any sharding holds it -> the flat, zero-padded layout of
+    the encode stages (C order; the out sharding places each shard)."""
+    flat = x.reshape(-1)
+    return jnp.pad(flat, (0, total - flat.size))
+
+
+# jit cache of `_gather_shard` stages, keyed by (mesh, axis, ln, size).
+_GATHER_FNS: Dict[Tuple, object] = {}
+
+
+def _gather_shard(x_l, pos, *, ln, axis):
+    """Per-shard gather of the values at global positions ``pos``
+    (replicated): each shard takes the positions it holds and the shards'
+    bit patterns are summed, so every value arrives exactly (a float sum
+    would turn -0.0 into 0.0)."""
+    s = jax.lax.axis_index(axis).astype(pos.dtype)
+    local = pos - s * ln
+    mine = (local >= 0) & (local < ln)
+    v = x_l[jnp.clip(local, 0, ln - 1)]
+    if v.dtype.itemsize == 2:
+        # Summed in int32 and narrowed back exactly.
+        bits = jax.lax.bitcast_convert_type(v, jnp.int16).astype(jnp.int32)
+    else:
+        # int32 words; an 8-byte value becomes a trailing pair of them.
+        bits = jax.lax.bitcast_convert_type(v, jnp.int32)
+    mine = mine.reshape(mine.shape + (1,) * (bits.ndim - 1))
+    bits = jax.lax.psum(jnp.where(mine, bits, 0), axis)
+    if v.dtype.itemsize == 2:
+        bits = bits.astype(jnp.int16)
+    return jax.lax.bitcast_convert_type(bits, v.dtype)
 
 
 def _analyze_shard(prev_l, curr_l, error_bound, *, max_bins, b_max,
@@ -173,6 +206,16 @@ class ShardedCompressor:
     thread, and the blobs are byte-identical to ``overlap=False`` -- both
     modes run the exact same shared finalize.
 
+    Inputs are NumPy arrays or ``jax.Array``s.  A ``jax.Array`` whose
+    devices are this compressor's mesh, in any sharding (a field born
+    sharded on the chips), stays there: one jitted program re-lays it out
+    into the flat ``P(axis)`` layout of the encode stages (span
+    ``encode.relayout``) and the exception values are gathered on the
+    mesh, so no host copy of the field is made.  Anything else (NumPy,
+    an array on other devices) is copied through the host, padded and
+    uploaded.  Counter ``encode.input_host_bytes`` counts the input bytes
+    each step copied through the host.
+
     ``chain`` picks the temporal reference chain residency: "auto"
     (default) keeps between-step state sharded and device-resident on the
     mesh whenever the dtype allows (f32, or f64 under jax_enable_x64),
@@ -204,10 +247,55 @@ class ShardedCompressor:
         self._encode_fns: Dict[Tuple, object] = {}
         self._advance_fns: Dict[Tuple, object] = {}
         self._entropy_fns: Dict[Tuple, object] = {}
+        self._relayout_fns: Dict[Tuple, object] = {}
 
     def _shardings(self):
         return (NamedSharding(self.mesh, P(self.axis)),
                 NamedSharding(self.mesh, P()))
+
+    def _on_mesh(self, arr) -> bool:
+        """Is ``arr`` a jax.Array held by exactly this mesh's devices?"""
+        return (isinstance(arr, jax.Array)
+                and arr.sharding.device_set == set(self.mesh.devices.flat))
+
+    def _relayout_fn(self, shape: tuple, dtype, sharding):
+        """One jitted program per (shape, dtype, input sharding): the field
+        flattened in C order and zero-padded to ``n_shards * ln``, placed
+        as ``P(axis)``.  Across chips this is a collective."""
+        key = (tuple(shape), np.dtype(dtype), sharding)
+        if key not in self._relayout_fns:
+            n = int(np.prod(shape))
+            total = self.n_shards * -(-n // self.n_shards)
+            # Named, so that the program is ``jit__relayout`` in a trace.
+            fn = update_wrapper(partial(_relayout, total=total), _relayout)
+            self._relayout_fns[key] = jax.jit(
+                fn, out_shardings=self._shardings()[0])
+        return self._relayout_fns[key]
+
+    def _relayout(self, arr, dtype=None):
+        """A mesh-resident field in the encode stages' layout (cast to
+        ``dtype`` when given), under span ``encode.relayout``."""
+        with telemetry.span("encode.relayout", n=arr.size):
+            out = self._relayout_fn(arr.shape, arr.dtype, arr.sharding)(arr)
+            if dtype is not None and out.dtype != dtype:
+                out = out.astype(dtype)
+            if telemetry.enabled():
+                jax.block_until_ready(out)
+        return out
+
+    def _gather_fn(self, ln: int, size: int):
+        """Exception values at replicated global positions (``size`` of
+        them, the padded count), gathered where the shards hold them.
+        Shared by every compressor on the same mesh, so a padded count
+        compiles once per process, whichever variable met it first."""
+        key = (self.mesh, self.axis, ln, size)
+        if key not in _GATHER_FNS:
+            fn = shard_map(
+                partial(_gather_shard, ln=ln, axis=self.axis),
+                mesh=self.mesh, in_specs=(P(self.axis), P()),
+                out_specs=P(), check_vma=False)
+            _GATHER_FNS[key] = jax.jit(fn)
+        return _GATHER_FNS[key]
 
     def _analyze_fn(self, ebytes: int, n: int):
         key = (ebytes, n)
@@ -308,18 +396,24 @@ class ShardedCompressor:
         return self._advance_fns[key]
 
     # -------------------------------------------------------- device stage
-    def _device_encode(self, prev, curr: np.ndarray,
+    def _device_encode(self, prev, curr,
                        b_bits: Optional[int] = None) -> DeviceEncoded:
         """Phases 1-5 on device; returns the pre-entropy encode result
         (host numpy) that both the finalize stage and the reconstructed-
         reference chain consume.
 
-        `prev` is either a host array (padded + device_put here) or the
-        mesh-resident chain state: an already padded, sharded f32
-        jax.Array of shape (n_shards * ln,), fed straight back in."""
+        `curr` is a host array (padded + device_put here) or a jax.Array
+        on this mesh in any sharding (re-laid out on the mesh; its
+        exception values are then gathered there too, and it is never
+        copied to the host).  `prev` is a host array, such a mesh-resident
+        field, or the mesh-resident chain state: an already padded,
+        sharded f32 jax.Array of shape (n_shards * ln,), fed straight
+        back in."""
         p = self.params
-        curr_f = np.asarray(curr, np.float32).reshape(-1)
-        n = curr_f.size
+        on_mesh = self._on_mesh(curr)
+        if not on_mesh:
+            curr = np.asarray(curr)
+        n = int(curr.size)
         if n >= (1 << 31):
             raise ValueError("per-variable n >= 2^31 needs jax_enable_x64 "
                              "(see pipeline offset note)")
@@ -327,18 +421,29 @@ class ShardedCompressor:
         ln = -(-n // P_)
         sharded, _ = self._shardings()
         # Pad so every shard holds ln elements; pads are invalid (prev=0).
-        if isinstance(prev, jax.Array):
-            if prev.shape != (P_ * ln,):
-                raise ValueError(
-                    f"device-resident chain state {prev.shape} does not "
-                    f"match this step's padded layout ({P_ * ln},); "
-                    "reset() the compressor before changing shapes")
+        if isinstance(prev, jax.Array) and prev.shape == (P_ * ln,):
             prev_dev = prev
+        elif self._on_mesh(prev) and prev.size == n:
+            prev_dev = self._relayout(prev, jnp.float32)
+        elif isinstance(prev, jax.Array):
+            raise ValueError(
+                f"device-resident chain state {prev.shape} does not "
+                f"match this step's padded layout ({P_ * ln},); "
+                "reset() the compressor before changing shapes")
         else:
             prev_f = np.asarray(prev, np.float32).reshape(-1)
             prev_dev = _put_sharded(_pad_to(prev_f, P_ * ln, 0.0), sharded)
-        curr_dev = _put_sharded(_pad_to(curr_f, P_ * ln, 0.0), sharded)
-        ebytes = np.dtype(np.asarray(curr).dtype).itemsize
+        if on_mesh:
+            # Source precision for the exception values, f32 for the
+            # encode stages (the same array when the field is f32).
+            curr_src = self._relayout(curr)
+            curr_dev = curr_src.astype(jnp.float32)
+            telemetry.counter("encode.input_host_bytes", 0)
+        else:
+            curr_f = np.asarray(curr, np.float32).reshape(-1)
+            curr_dev = _put_sharded(_pad_to(curr_f, P_ * ln, 0.0), sharded)
+            telemetry.counter("encode.input_host_bytes", curr.nbytes)
+        ebytes = np.dtype(curr.dtype).itemsize
 
         analyze = self._analyze_fn(ebytes, n)
         # The b_auto fetch is a device sync point: the analyze span covers
@@ -368,9 +473,22 @@ class ShardedCompressor:
                 jax.block_until_ready((idx_dev, packed, valid))
 
         marker = (1 << bb) - 1
+        exc_vals = None
         with telemetry.span("encode.exceptions") as sp_exc:
-            exc_counts, exc_pos = kops.exception_compact(
-                idx_dev.reshape(-1), n, marker, be)
+            if on_mesh:
+                exc_counts, pos_dev, k = kops.exception_positions(
+                    idx_dev.reshape(-1), n, marker, be)
+                exc_pos = np.zeros(0, np.int64)
+                exc_vals = np.zeros(0, curr.dtype)
+                if k:
+                    vals_dev = self._gather_fn(ln, pos_dev.size)(curr_src,
+                                                                 pos_dev)
+                    pos_h, vals_h = jax.device_get((pos_dev, vals_dev))
+                    exc_pos = pos_h[:k].astype(np.int64)
+                    exc_vals = vals_h[:k]
+            else:
+                exc_counts, exc_pos = kops.exception_compact(
+                    idx_dev.reshape(-1), n, marker, be)
             valid_np = np.asarray(valid).reshape(-1)
         nblocks = -(-n // be)
         nbytes_block = be * bb // 8
@@ -412,12 +530,16 @@ class ShardedCompressor:
                                   n=n, packed=raws, entropy_coded=coded,
                                   entropy_codec=coded_name,
                                   exc_positions=exc_pos,
-                                  exc_block_counts=exc_counts)
+                                  exc_block_counts=exc_counts,
+                                  exc_values=exc_vals,
+                                  dtype=curr.dtype if on_mesh else None,
+                                  shape=tuple(curr.shape) if on_mesh
+                                  else None)
         domain_lo = float(np.asarray(domain_lo))
         width = float(np.asarray(width))
         centers = pipe.topk_centers(np.asarray(ids_desc), k_eff,
                                     domain_lo, width)
-        centers = pipe.round_centers(centers, np.asarray(curr).dtype)
+        centers = pipe.round_centers(centers, curr.dtype)
         meta = {"b_auto": b_auto,
                 "est_sizes": np.asarray(est_sizes).tolist(),
                 "n_shards": self.n_shards, "pipeline": "sharded"}
@@ -435,26 +557,36 @@ class ShardedCompressor:
                              idx_dev=idx_dev, curr_dev=curr_dev)
 
     # --------------------------------------------------------- host stage
-    def compress_async(self, prev: np.ndarray, curr: np.ndarray,
-                       b_bits: Optional[int] = None
+    def _finalize_input(self, curr, dev: DeviceEncoded):
+        """What the finalize of a step reads of its input: nothing when
+        the exception values came from the mesh, else a host array (a
+        private copy under overlap, so callers may reuse their buffers)."""
+        if dev.enc.exc_values is not None:
+            return None
+        return np.array(curr, copy=True) if self.overlap else np.asarray(curr)
+
+    def compress_async(self, prev, curr, b_bits: Optional[int] = None
                        ) -> "Future[CompressedStep]":
         """Device-encode now; return a future of the finalized step
         (finalize runs on the background thread when overlap=True, with at
         most two in flight).
 
-        `curr` is snapshotted before the background finalize reads it
-        (exception values), so callers may reuse their buffers.
+        `prev` and `curr` are NumPy arrays or jax.Arrays; one on this
+        mesh, in any sharding, is encoded where it lies and never copied
+        to the host (see ``_device_encode``).  A host `curr` is
+        snapshotted before the background finalize reads it (exception
+        values), so callers may reuse their buffers; a device array is
+        immutable and needs no snapshot.
         """
         dev = self._device_encode(prev, curr, b_bits)
         step_i, self._step = self._step, self._step + 1
-        curr_s = (np.array(curr, copy=True) if self.overlap
-                  else np.asarray(curr))
-        return self._q.submit(pipe.finalize_step, curr_s, dev.enc,
+        return self._q.submit(pipe.finalize_step,
+                              self._finalize_input(curr, dev), dev.enc,
                               dev.centers, dev.domain_lo, dev.width,
                               self.params, dev.meta,
                               label=f"finalize step {step_i}")
 
-    def compress(self, prev: np.ndarray, curr: np.ndarray,
+    def compress(self, prev, curr,
                  b_bits: Optional[int] = None) -> CompressedStep:
         return self.compress_async(prev, curr, b_bits).result()
 
@@ -465,28 +597,36 @@ class ShardedCompressor:
         return chainmod.HostReferenceChain()
 
     # ------------------------------------------------- temporal streaming
-    def add_async(self, arr: np.ndarray) -> "Future[CompressedStep]":
+    def add_async(self, arr) -> "Future[CompressedStep]":
         """Streaming interface over a temporal series (first call stores a
         lossless anchor).  The reference chain advances from the
         pre-entropy encode result before returning, so the next step's
         device work never waits on this step's entropy stage; with the
         default device-resident chain the state also never leaves the
-        mesh."""
-        arr = np.asarray(arr)
+        mesh.
+
+        `arr` is a NumPy array or a jax.Array.  One on this mesh, in any
+        sharding, stays there: a delta step is encoded where it lies, and
+        only the anchor's lossless host encode fetches it (once).  Any
+        other input is copied through the host."""
         step_i, self._step = self._step, self._step + 1
+        if not self._on_mesh(arr):
+            arr = np.asarray(arr)
         if self._chain is None or self._chain.empty:
             self._chain = self._make_chain(arr.dtype)
             self._chain.seed(arr)
-            return self._q.submit(pipe.finalize_anchor, arr.copy(),
-                                  self.params,
+            telemetry.counter("encode.input_host_bytes", arr.nbytes)
+            host = (np.asarray(arr) if isinstance(arr, jax.Array)
+                    else arr.copy())
+            return self._q.submit(pipe.finalize_anchor, host, self.params,
                                   label=f"anchor step {step_i}")
         dev = self._device_encode(self._chain.peek(), arr)
         if self.params.reference == REF_RECONSTRUCTED:
             self._chain.advance(dev, arr)
         else:
             self._chain.replace(arr)
-        curr_s = np.array(arr, copy=True) if self.overlap else arr
-        return self._q.submit(pipe.finalize_step, curr_s, dev.enc,
+        return self._q.submit(pipe.finalize_step,
+                              self._finalize_input(arr, dev), dev.enc,
                               dev.centers, dev.domain_lo, dev.width,
                               self.params, dev.meta,
                               label=f"finalize step {step_i}")
@@ -615,14 +755,25 @@ class _ShardedDeviceChain(chainmod.ReferenceChain):
         sharded, _ = d._shardings()
         return _put_sharded(_pad_to(flat, d.n_shards * ln, 0.0), sharded)
 
+    def _layout(self, arr):
+        """``arr`` in the chain's padded, sharded layout and precision: a
+        field on the mesh through the driver's relayout, anything else
+        through the host."""
+        if self._d._on_mesh(arr):
+            return self._d._relayout(
+                arr, pipe.reconstruction_dtype(arr.dtype))
+        return self._pad_put(arr)
+
     def seed(self, arr) -> None:
-        arr = np.asarray(arr)
+        if not self._d._on_mesh(arr):
+            arr = np.asarray(arr)
         if not chainmod.device_supports(arr.dtype):
             raise ValueError(
                 f"mesh-resident chain cannot hold {arr.dtype} bit-exactly "
                 "(float64 needs jax_enable_x64)")
-        self._n, self._shape, self._dtype = arr.size, arr.shape, arr.dtype
-        self._state = self._pad_put(arr)
+        self._n, self._shape = arr.size, tuple(arr.shape)
+        self._dtype = np.dtype(arr.dtype)
+        self._state = self._layout(arr)
 
     def replace(self, arr) -> None:
         self.seed(arr)
@@ -637,7 +788,7 @@ class _ShardedDeviceChain(chainmod.ReferenceChain):
         # dev.curr_dev is the encode stages' f32 copy; a float64 chain
         # (x64) must patch exceptions from the source-precision values.
         curr_dev = (dev.curr_dev if self._state.dtype == jnp.float32
-                    else self._pad_put(np.asarray(curr)))
+                    else self._layout(curr))
         fn = self._d._advance_fn(bb)
         self._state = fn(dev.idx_dev.reshape(-1), self._state,
                          curr_dev, centers)

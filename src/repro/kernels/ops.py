@@ -59,15 +59,14 @@ def patch_exceptions(recon, idx, exc_values, *, b_bits):
     return dequant.patch_exceptions(recon, idx, exc_values, b_bits=b_bits)
 
 
-def exception_compact(idx, n, marker, block_elems):
-    """Device-side incompressible compaction for the encode stage.
+def exception_positions(idx, n, marker, block_elems):
+    """Device-side incompressible compaction, left on the device.
 
-    Returns (per-block marker counts (nblocks,) int64, ascending marker
-    positions (k,) int64) computed on device -- the host finalize gathers
-    the k exception values by position instead of re-scanning the full
-    index table with a boolean mask.  The nonzero size is padded to the
-    next power of two so the jit cache stays bounded (<= log2(n) entries)
-    across steps with varying exception counts.
+    Returns (per-block marker counts (nblocks,) int64 on the host, the
+    ascending marker positions on the device padded with ``n`` to the next
+    power of two of their count, and that count ``k``).  The padding keeps
+    the jit cache bounded (<= log2(n) entries) across steps with varying
+    exception counts.  ``k == 0`` gives no positions (None).
     """
     flat = jnp.asarray(idx).reshape(-1)[:n]
     mask = flat == marker
@@ -79,9 +78,23 @@ def exception_compact(idx, n, marker, block_elems):
     ).astype(np.int64)
     k = int(counts.sum())
     if k == 0:
-        return counts, np.zeros(0, np.int64)
+        return counts, None, 0
     size = min(1 << (k - 1).bit_length(), n)
     (pos,) = jnp.nonzero(mask, size=size, fill_value=n)
+    return counts, pos, k
+
+
+def exception_compact(idx, n, marker, block_elems):
+    """Device-side incompressible compaction for the encode stage.
+
+    Returns (per-block marker counts (nblocks,) int64, ascending marker
+    positions (k,) int64) computed on device -- the host finalize gathers
+    the k exception values by position instead of re-scanning the full
+    index table with a boolean mask (see ``exception_positions``).
+    """
+    counts, pos, k = exception_positions(idx, n, marker, block_elems)
+    if k == 0:
+        return counts, np.zeros(0, np.int64)
     return counts, np.asarray(pos)[:k].astype(np.int64)
 
 
@@ -113,5 +126,6 @@ def chain_advance(idx, prev, curr, centers, *, b_bits,
 
 
 __all__ = ["change_ratio_bins", "pack_bits", "dequantize",
-           "patch_exceptions", "exception_compact", "chain_advance",
+           "patch_exceptions", "exception_positions", "exception_compact",
+           "chain_advance",
            "chain_advance_core", "histogram"]
